@@ -23,6 +23,7 @@ import sys
 import traceback
 
 from repro.api import dump_dicts
+from repro.core import backend
 
 from . import (analysis_accuracy, api_overhead, calibrate_roundtrip,
                desync_scaling, fig6_full_domain, fig7_symmetric, fig8_error,
@@ -75,6 +76,7 @@ def main() -> None:
                          "stdout")
     args = ap.parse_args()
     keys = [args.only] if args.only else list(MODULES)
+    backend.enable_compile_cache()
 
     if args.ndjson:
         failures: dict[str, str] = {}

@@ -18,6 +18,8 @@ import subprocess
 import sys
 import time
 
+# Imports jax but never initialises a backend: the chip stays free for
+# the server process.
 from repro.serve import client
 
 HOST = "127.0.0.1"

@@ -6,7 +6,7 @@ streams and its flops per iteration.  This package derives both from
 the kernel's own jaxpr instead of a hand-transcribed table:
 
   traffic  — :func:`audit`: walk the closed jaxpr (through
-             pallas_call / scan / while / pjit / cond), classify every
+             pallas_call / scan / while / jit / cond), classify every
              buffer as a streaming load, store, RFO write-allocate,
              resident operand, or accumulator, and count flops.
   features — :func:`features` / :func:`derive`: collapse a

@@ -146,7 +146,7 @@ def _lint_bucket_bypass(closed, target: str) -> list[Diagnostic]:
     out = []
     for jaxpr in _iter_jaxprs(closed.jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name != "pjit":
+            if eqn.primitive.name != "jit":
                 continue
             for iv in eqn.invars:
                 aval = getattr(iv, "aval", None)
@@ -175,12 +175,8 @@ def _lint_bucket_bypass(closed, target: str) -> list[Diagnostic]:
 def _lint_f64_promotion(fn, args, target: str) -> list[Diagnostic]:
     if not HAVE_JAX:
         return []
-    try:
-        from jax.experimental import enable_x64
-        with enable_x64():
-            closed = jax.make_jaxpr(fn)(*args)
-    except Exception:  # noqa: BLE001 — a fn that only traces in x32
-        return []      # mode cannot promote; nothing to report
+    with backend_mod.x64():
+        closed = jax.make_jaxpr(fn)(*args)
     out = []
     for jaxpr in _iter_jaxprs(closed.jaxpr):
         for eqn in jaxpr.eqns:

@@ -76,7 +76,8 @@ def _map_case(name: str, n_arrays: int, *, in_place: bool = False,
         s = jnp.arange(1, scalars + 1, dtype=jnp.float32) if scalars > 1 \
             else jnp.float32(3.0)
         arrays = tuple(jnp.ones(n, jnp.float32) for _ in range(n_arrays))
-        return (functools.partial(map_stream, name, in_place=in_place),
+        return (functools.partial(map_stream, name, in_place=in_place,
+                                  interpret=True),
                 (s, *arrays))
     return build
 
@@ -87,7 +88,8 @@ def _reduce_case(name: str, n_arrays: int):
         from ..kernels.stream import LANES, reduce_stream
         n = LANES * 64
         arrays = tuple(jnp.ones(n, jnp.float32) for _ in range(n_arrays))
-        return functools.partial(reduce_stream, name), arrays
+        return (functools.partial(reduce_stream, name, interpret=True),
+                arrays)
     return build
 
 
@@ -97,10 +99,11 @@ def _jacobi_case(version: int):
         from ..kernels.jacobi import jacobi_v1, jacobi_v2
         a = jnp.ones((66, 128), jnp.float32)
         if version == 1:
-            return jacobi_v1, (a, jnp.float32(0.25))
+            return (functools.partial(jacobi_v1, interpret=True),
+                    (a, jnp.float32(0.25)))
         f = jnp.ones((66, 128), jnp.float32)
         return (functools.partial(jacobi_v2, ax=0.25, ay=0.25, b1=0.5,
-                                  relax=1.0), (a, f))
+                                  relax=1.0, interpret=True), (a, f))
     return build
 
 
@@ -208,7 +211,8 @@ def lint_corpus() -> list:
     from ..kernels.rmsnorm import rmsnorm
     x = jnp.ones((64, 128), jnp.float32)
     w = jnp.ones((128,), jnp.float32)
-    diags += lint_callable(rmsnorm, x, w, name="rmsnorm")
+    diags += lint_callable(functools.partial(rmsnorm, interpret=True), x, w,
+                           name="rmsnorm")
 
     from .. import api
     batch = api.ScenarioBatch([

@@ -12,7 +12,7 @@ jax/pallas kernels, operating on the *closed jaxpr* instead of C source:
   it depends on (backward reachability over the index-map jaxpr), which
   yields how often the block is (re)fetched across the sequential grid
   walk and therefore the stream's total element traffic;
-* ``scan`` / ``while`` / ``cond`` / ``pjit`` (and the other call-like
+* ``scan`` / ``while`` / ``cond`` / ``jit`` (and the other call-like
   primitives) are recursed into, multiplying trip counts where they are
   static and recording a note where they are not;
 * flops are counted per arithmetic primitive (elementwise ops charge
@@ -54,7 +54,7 @@ _VIEW_PRIMS = frozenset({
 
 #: Call-like primitives recursed into with an unchanged trip multiplier.
 _CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "custom_jvp_call",
+    "jit", "closed_call", "core_call", "custom_jvp_call",
     "custom_vjp_call", "remat", "checkpoint", "custom_vjp_call_jaxpr",
 })
 
@@ -291,13 +291,7 @@ def _index_map_deps(index_map_jaxpr, n_axes: int) -> list[int]:
 
 
 def _block_elems(block_shape) -> int:
-    n = 1
-    for d in block_shape:
-        try:
-            n *= max(int(d), 1)
-        except (TypeError, ValueError):  # pallas Mapped / squeezed dims
-            n *= 1
-    return n
+    return int(math.prod(max(d, 1) for d in block_shape))
 
 
 def _fetches(deps: Sequence[int], grid: Sequence[int]) -> int:
@@ -342,9 +336,10 @@ def _audit_pallas(eqn, env: dict, mult: float, st: _State) -> None:
     def _stream(bm, aval, base, is_output, out_idx=None):
         deps = _index_map_deps(bm.index_map_jaxpr, n_axes)
         fetches = _fetches(deps, grid)
-        block_shape = tuple(
-            d if isinstance(d, int) else 1
-            for d in (bm.block_shape or getattr(aval, "shape", ())))
+        # Blocked / Element / BoundedSlice dims carry their size;
+        # a Squeezed dim moves one element.
+        block_shape = tuple(getattr(d, "block_size", 1)
+                            for d in bm.block_shape)
         elements = _block_elems(block_shape) * fetches
         itemsize = int(getattr(getattr(aval, "dtype", None), "itemsize", 4))
         if is_output:

@@ -432,7 +432,7 @@ if HAVE_JAX:
             ("calibrate.fit_scaling", utilization, refine, Cb, N,
              len(f_grid), n_max_b),
             lambda: _build_jax_fit(utilization, n_max_b, refine))
-        with jax.experimental.enable_x64():
+        with backend_mod.x64():
             # Padded cells are all-masked: their fit runs on zeros and
             # is sliced off below, so real cells are bit-for-bit the
             # unpadded pass.

@@ -26,6 +26,9 @@ This module is the single implementation:
   function over slabs of the batch axis and stitches the results, so a
   B far beyond memory streams through a bounded working set
   (``REPRO_CHUNK_B`` sets a process-wide default slab).
+* **numerics and compile cache** — :func:`x64` is the one float64 scope
+  every jitted solver runs in, and :func:`enable_compile_cache` the one
+  persistent-compilation-cache set-up every entry point calls.
 
 A future backend (pallas kernels, multi-device sharding) registers
 here once — a new ``resolve`` target plus its ``jitted`` builders —
@@ -35,6 +38,7 @@ instead of being threaded through four modules.
 from __future__ import annotations
 
 import os
+import pathlib
 import threading
 import time
 from typing import Callable, Sequence
@@ -149,6 +153,49 @@ def resolve(backend: str, batch_size: int | None = None, *,
     if backend == "numpy":
         return "numpy"
     raise ValueError(f"unknown backend {backend!r}")
+
+
+def x64():
+    """Context in which jax traces and runs in float64: the numerics of
+    every jitted solver (sharing, desync, calibration fits).
+
+    It stays a scope around the solver calls and never becomes a
+    process-wide flag: Mosaic refuses to compile the Pallas kernels
+    when they are traced with x64 on."""
+    return jax.enable_x64(True)
+
+
+def device_info() -> dict:
+    """The device jax computes on, as every result names it: its
+    ``platform``, ``device_kind`` and the process's device count."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+#: The compile cache's directory when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: fixed inside the checkout, because the path is part of what a
+#: later process must find again.
+DEFAULT_COMPILE_CACHE = pathlib.Path(__file__).resolve().parents[3] / \
+    ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no other directory is set; otherwise the cache goes to
+    :data:`DEFAULT_COMPILE_CACHE`.  Every executable is cached, however
+    quickly it compiled, so the small solver buckets are kept too.
+    Called by the entry points (``chip_smoke.py``, ``python -m
+    repro.serve``, ``benchmarks/run.py``), never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_COMPILE_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 # ---------------------------------------------------------------------------

@@ -713,7 +713,7 @@ def _run_jax(enc: _Encoded, arch: str, specs, placement, t_max: float,
     runner = backend_mod.jitted(
         ("desync.run_batch", Bb, R, Lb, K, D),
         lambda: _build_jax_runner(Bb, R, Lb, K, D))
-    with jax.experimental.enable_x64():
+    with backend_mod.x64():
         out = runner(jnp.asarray(kind_p, jnp.int32),
                      jnp.asarray(qty_p, jnp.float64),
                      jnp.asarray(kern_p, jnp.int32),

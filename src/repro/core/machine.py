@@ -198,3 +198,7 @@ TPU_V5E = TpuModel(
     ici_link_gbs=50.0,
     ici_links=4,
 )
+
+#: TPU machine models by the ``device_kind`` JAX reports.  A device that
+#: is missing here has no known peaks, and callers treat it as an error.
+TPU_BY_DEVICE_KIND: dict[str, TpuModel] = {"TPU v5 lite": TPU_V5E}

@@ -535,7 +535,7 @@ if HAVE_JAX:
         solver = backend_mod.jitted(
             ("sharing.solve_batch", mode, Bb, G, n_max_b),
             lambda: _build_jax_solver(mode, n_max_b))
-        with jax.experimental.enable_x64():
+        with backend_mod.x64():
             out = solver(
                 jnp.asarray(backend_mod.pad_rows(n, Bb), jnp.float64),
                 jnp.asarray(backend_mod.pad_rows(
@@ -767,7 +767,7 @@ def solve_arrays_and_grad(n, f, bs, *, wrt=("f", "b_s"),
         lambda: _build_jax_grad_solver(mode, n_max_b, beta, argnums))
     with trace.span("sharing.solve_grad", wrt=",".join(wrt), B=B, G=G,
                     mode=mode):
-        with jax.experimental.enable_x64():
+        with backend_mod.x64():
             jacs = solver(
                 jnp.asarray(backend_mod.pad_rows(n, Bb), jnp.float64),
                 jnp.asarray(backend_mod.pad_rows(f, Bb), jnp.float64),

@@ -69,7 +69,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, out_ref,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      lengths: jax.Array, *, scale: float | None = None,
-                     block_k: int = 512, interpret: bool = True
+                     block_k: int = 512, interpret: bool
                      ) -> jax.Array:
     """Single-token attention against a KV cache.
 

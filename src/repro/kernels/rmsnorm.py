@@ -43,7 +43,7 @@ def _blocks(rows: int, block_rows: int) -> tuple[int, int]:
 
 def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
             block_rows: int = DEFAULT_BLOCK_ROWS,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool) -> jax.Array:
     """y = x / rms(x) * w over the last axis.  x: (..., hidden)."""
     shape = x.shape
     hidden = shape[-1]
@@ -66,7 +66,7 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
 
 def rmsnorm_residual(x: jax.Array, residual: jax.Array, w: jax.Array, *,
                      eps: float = 1e-6, block_rows: int = DEFAULT_BLOCK_ROWS,
-                     interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                     interpret: bool) -> tuple[jax.Array, jax.Array]:
     """Fused h = x + residual; y = rmsnorm(h) * w.  Returns (y, h)."""
     shape = x.shape
     hidden = shape[-1]

@@ -69,6 +69,7 @@ def main(argv: "list[str] | None" = None) -> int:
         default_deadline_s=(args.deadline_ms / 1e3
                             if args.deadline_ms > 0 else None),
         cache_entries=args.cache_entries)
+    backend_mod.enable_compile_cache()
     return asyncio.run(_serve(args, config))
 
 
@@ -79,10 +80,11 @@ async def _serve(args, config: ServeConfig) -> int:
         built = app.cache.warmup(_warmup_scenario(spec), buckets=buckets)
         print(f"warmup {spec}: {built} plan(s) compiled", flush=True)
     port = await app.start(args.host, args.port)
+    device = backend_mod.device_info()
     print(f"repro.serve: serving on http://{args.host}:{port} "
           f"(tick {config.tick_s * 1e3:g} ms, max_batch "
-          f"{config.max_batch}, backend substrate "
-          f"{'jax+numpy' if backend_mod.HAVE_JAX else 'numpy'})",
+          f"{config.max_batch}, jax on {device['count']} "
+          f"{device['platform']} device(s), {device['kind']})",
           flush=True)
 
     stop = asyncio.Event()

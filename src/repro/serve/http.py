@@ -6,8 +6,8 @@ only — no framework): POST an ndjson body of request lines to
 ``/v1/simulate``) and the responses stream back as chunked ndjson, one
 line per request **in request order**, as each one's coalesced solve
 lands.  ``GET /healthz`` answers liveness (503 while draining);
-``GET /statsz`` returns the plan-cache, coalescer, and substrate cache
-stats (``backend.cache_stats(scope="all")``) as one JSON document.
+``GET /statsz`` returns the device, plan-cache, coalescer, and substrate
+cache stats (``backend.cache_stats(scope="all")``) as one JSON document.
 
 Connections are one-shot (``Connection: close``): the client idiom is
 one POST per workload, many lines per POST — coalescing happens across
@@ -81,6 +81,7 @@ class App:
     def statsz(self) -> dict:
         return {
             "uptime_s": round(time.monotonic() - self._t0, 3),
+            "device": backend_mod.device_info(),
             "coalescer": self.coalescer.stats(),
             "plan_cache": self.cache.stats(),
             "caches": backend_mod.cache_stats(scope="all"),

@@ -18,7 +18,7 @@ from repro import api
 from repro.analysis import audit, derive, features
 from repro.analysis.report import cross_check, static_suite
 from repro.core.table2 import TABLE2, KernelSpec
-from repro.kernels.stream import LANES, map_stream, reduce_stream
+from repro.kernels.stream import LANES, map_stream, reduce_stream, row_block
 
 jax.config.update("jax_enable_x64", False)
 
@@ -28,7 +28,13 @@ N = LANES * 64
 def _map(name, n_arrays, n=N, **kw):
     s = jnp.float32(3.0)
     arrays = tuple(jnp.ones(n, jnp.float32) for _ in range(n_arrays))
-    return functools.partial(map_stream, name, **kw), (s, *arrays)
+    return (functools.partial(map_stream, name, interpret=True, **kw),
+            (s, *arrays))
+
+
+def _jacobi_v1():
+    from repro.kernels.jacobi import jacobi_v1
+    return functools.partial(jacobi_v1, interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -57,7 +63,7 @@ def test_golden_stream_triad():
 
 
 def test_golden_jacobi_v1_layer_condition():
-    from repro.kernels.jacobi import jacobi_v1
+    jacobi_v1 = _jacobi_v1()
     a = jnp.ones((66, 128), jnp.float32)
     lc = features(jacobi_v1, a, jnp.float32(0.25), reuse=True)
     assert (lc.reads, lc.writes, lc.rfo) == (1, 1, 1)   # JacobiL2-v1
@@ -69,7 +75,7 @@ def test_golden_jacobi_v1_layer_condition():
 
 
 def test_jacobi_views_share_one_base():
-    from repro.kernels.jacobi import jacobi_v1
+    jacobi_v1 = _jacobi_v1()
     a = jnp.ones((66, 128), jnp.float32)
     tr = audit(jacobi_v1, a, jnp.float32(0.25))
     bases = {s.base for s in tr.loads}
@@ -128,15 +134,15 @@ def test_in_place_numerics_unchanged():
     b = jnp.asarray(rng.standard_normal(N), jnp.float32)
     s = jnp.float32(1.7)
     np.testing.assert_allclose(
-        map_stream("daxpy", s, a, b, in_place=True),
-        map_stream("daxpy", s, a, b), rtol=1e-6)
+        map_stream("daxpy", s, a, b, in_place=True, interpret=True),
+        map_stream("daxpy", s, a, b, interpret=True), rtol=1e-6)
 
 
 def test_in_place_rejects_distinct_output_kernels():
     s = jnp.float32(1.0)
     a = jnp.ones(N, jnp.float32)
     with pytest.raises(ValueError, match="dscal"):
-        map_stream("dcopy", s, a, in_place=True)
+        map_stream("dcopy", s, a, in_place=True, interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -145,13 +151,14 @@ def test_in_place_rejects_distinct_output_kernels():
 
 
 def test_multi_step_grid_counts_all_fetches():
-    fn, args = _map("dcopy", 1, n=LANES * 512)   # grid (2,)
+    rows = 2 * row_block(1 << 20, LANES * 4, 2, 4)   # grid (2,)
+    fn, args = _map("dcopy", 1, n=LANES * rows)
     tr = audit(fn, *args)
     (load,) = tr.loads
     assert load.fetches == 2
-    assert load.elements == LANES * 512
+    assert load.elements == LANES * rows
     lf = derive(tr)
-    assert lf.iters == LANES * 512
+    assert lf.iters == LANES * rows
     assert (lf.reads, lf.writes, lf.rfo) == (1, 1, 1)
 
 
@@ -160,11 +167,11 @@ def test_scan_multiplies_traffic():
     a = jnp.ones(N, jnp.float32)
 
     def once(s, a):
-        return map_stream("dscal", s, a)
+        return map_stream("dscal", s, a, interpret=True)
 
     def repeated(s, a):
         def body(carry, _):
-            return map_stream("dscal", s, carry), None
+            return map_stream("dscal", s, carry, interpret=True), None
         out, _ = jax.lax.scan(body, a, None, length=3)
         return out
 
@@ -186,7 +193,7 @@ def test_fallback_pure_jnp_boundary_traffic():
 
 def test_reduction_accumulator_not_a_store_stream():
     fn, args = _map("dcopy", 1)  # placeholder to keep args style
-    rfn = functools.partial(reduce_stream, "ddot2")
+    rfn = functools.partial(reduce_stream, "ddot2", interpret=True)
     arrays = (jnp.ones(N, jnp.float32), jnp.ones(N, jnp.float32))
     tr = audit(rfn, *arrays)
     assert not tr.stores            # (1,1) accumulator is grid-resident

@@ -213,7 +213,7 @@ def test_from_static_analysis_unknown_machine_suggests():
     import jax.numpy as jnp
 
     from repro.kernels.stream import map_stream
-    fn = functools.partial(map_stream, "dcopy")
+    fn = functools.partial(map_stream, "dcopy", interpret=True)
     args = (jnp.float32(1.0), jnp.ones(1024, jnp.float32))
     with pytest.raises(KeyError, match=r"did you mean 'ROME'"):
         api.from_static_analysis(fn, args, machine="ROME2")

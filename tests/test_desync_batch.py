@@ -311,7 +311,12 @@ def test_single_draw_matches_scalar_engine():
         progs.append(prog)
     recs = DesyncSimulator(progs, "TPU", specs=specs).run(t_max=120.0)
     want = skewness(durations_by_tag(recs, "probe", n_ranks=12))
-    assert got == want
+    # The batch engine's contention solves go through the jitted jax
+    # solver, which keeps a few ULP of compiler latitude against the
+    # scalar numpy engine (docs/known-issues.md); the skewness of the
+    # durations carries that difference through.
+    assert got == pytest.approx(want, rel=16 * np.finfo(np.float64).eps,
+                                abs=0.0)
 
 
 def test_pod_plan_candidates_evaluated_as_one_batch():

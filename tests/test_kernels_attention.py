@@ -93,7 +93,9 @@ def test_decode_attention_length_masking():
 # --------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("h,w", [(18, 128), (66, 256), (130, 384)])
+# (53, 4096): several VMEM-sized row blocks, the last one overhanging.
+@pytest.mark.parametrize("h,w", [(18, 128), (66, 256), (130, 384),
+                                 (53, 4096)])
 def test_jacobi_v1_matches_ref(h, w):
     rng = np.random.default_rng(7)
     a = jnp.asarray(rng.standard_normal((h, w)), jnp.float32)
@@ -102,7 +104,7 @@ def test_jacobi_v1_matches_ref(h, w):
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
-@pytest.mark.parametrize("h,w", [(18, 128), (34, 256)])
+@pytest.mark.parametrize("h,w", [(18, 128), (34, 256), (53, 4096)])
 def test_jacobi_v2_matches_ref(h, w):
     rng = np.random.default_rng(11)
     a = jnp.asarray(rng.standard_normal((h, w)), jnp.float32)

@@ -30,7 +30,8 @@ def _scalar(name):
 
 
 @pytest.mark.parametrize("name,n_in", sorted(MAP_CASES.items()))
-@pytest.mark.parametrize("n", [128, 1024, 128 * 300])
+# 128 * 2725: several VMEM-sized blocks, the last one overhanging.
+@pytest.mark.parametrize("n", [128, 1024, 128 * 300, 128 * 2725])
 def test_map_kernels_match_ref(name, n_in, n):
     arrays = _arrays(n_in, n, jnp.float32)
     s = _scalar(name)
@@ -40,7 +41,7 @@ def test_map_kernels_match_ref(name, n_in, n):
 
 
 @pytest.mark.parametrize("name,n_in", sorted(REDUCE_CASES.items()))
-@pytest.mark.parametrize("n", [128, 2048, 128 * 300])
+@pytest.mark.parametrize("n", [128, 2048, 128 * 300, 128 * 2725])
 def test_reduce_kernels_match_ref(name, n_in, n):
     arrays = _arrays(n_in, n, jnp.float32, seed=1)
     got = ops.stream_reduce(name, *arrays, impl="interpret")
@@ -78,4 +79,5 @@ def test_map_shape_sweep(rows, block, name):
 def test_non_multiple_of_lanes_raises():
     with pytest.raises(ValueError, match="multiple"):
         from repro.kernels.stream import map_stream
-        map_stream("dcopy", jnp.asarray(0.0), jnp.ones(100))
+        map_stream("dcopy", jnp.asarray(0.0), jnp.ones(100),
+                   interpret=True)
