@@ -158,7 +158,7 @@ def write_demo_trace(path: str) -> dict:
     """One fully-traced run touching every layer: jit compile (backend),
     placed-batch predict (api -> sharing), desync simulate (desync).
     Writes the Chrome trace_event artifact and returns span-name counts."""
-    backend_mod.clear_jit_cache()    # force backend.jit.build spans
+    backend_mod.clear_jit_cache()    # force first-call XLA compiles
     trace.enable(capacity=1 << 18, clear_events=True)
     try:
         plan = api.compile(api.ScenarioBatch.of(_placed_scenarios(64)))

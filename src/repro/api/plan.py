@@ -853,22 +853,24 @@ def _compile_simulate(scenario, *,
     t_max_conflict = None
     programs_batch = []
     placement0: Sequence[str] | None = None
-    for i, (sc, member) in enumerate(members):
-        if sc.arch != first.arch:
-            raise ValueError("all simulated scenarios must share one arch")
-        if t_max_conflict is None and sc.t_max != first.t_max:
-            t_max_conflict = (i, sc.t_max, first.t_max)
-        if sc.topo != first.topo:
-            raise ValueError(
-                f"scenario {i} uses a different topology than "
-                f"scenario 0; a batch shares one topology")
-        progs, placement = _programs_for(sc, member)
-        if i == 0:
-            placement0 = placement
-        elif placement != placement0:
-            raise ValueError(
-                "all simulated scenarios must share one placement")
-        programs_batch.append(progs)
+    with trace.span("api.compile.expand", members=len(members)):
+        for i, (sc, member) in enumerate(members):
+            if sc.arch != first.arch:
+                raise ValueError(
+                    "all simulated scenarios must share one arch")
+            if t_max_conflict is None and sc.t_max != first.t_max:
+                t_max_conflict = (i, sc.t_max, first.t_max)
+            if sc.topo != first.topo:
+                raise ValueError(
+                    f"scenario {i} uses a different topology than "
+                    f"scenario 0; a batch shares one topology")
+            progs, placement = _programs_for(sc, member)
+            if i == 0:
+                placement0 = placement
+            elif placement != placement0:
+                raise ValueError(
+                    "all simulated scenarios must share one placement")
+            programs_batch.append(progs)
 
     topo = first.topo
     if placement0 is not None and topo is None:

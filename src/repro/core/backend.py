@@ -21,7 +21,8 @@ This module is the single implementation:
   (:func:`bucket` rounds sizes up to powers of two), so sweeping over
   nearby batch sizes reuses one XLA executable instead of recompiling
   per shape.  :func:`cache_stats` exposes hit/miss counters — the
-  plan-overhead benchmark records the hit rate.
+  plan-overhead benchmark records the hit rate — and the XLA compiles
+  the process made, counted from ``jax.monitoring`` events.
 * **chunked streaming** — :func:`run_chunked` executes an array
   function over slabs of the batch axis and stitches the results, so a
   B far beyond memory streams through a bounded working set
@@ -40,12 +41,11 @@ from __future__ import annotations
 import os
 import pathlib
 import threading
-import time
 from typing import Callable, Sequence
 
 import numpy as np
 
-from ..obs import metrics, trace
+from ..obs import metrics
 
 try:  # The jax paths are optional: numpy covers hermetic containers.
     import jax  # noqa: F401  (re-exported capability, used by clients)
@@ -205,13 +205,46 @@ def enable_compile_cache() -> str:
 _JIT_CACHE: dict[tuple, Callable] = {}
 _JIT_LOCK = threading.Lock()
 
-# Hit/miss/compile-time accounting lives on the process-wide metrics
-# registry (repro.obs.metrics) — this module's former private ``_STATS``
-# dict, now visible to every exporter.  One labeled instrument per cache
-# key gives :func:`cache_stats` its per-bucket breakdown.
+# Hit/miss accounting lives on the process-wide metrics registry
+# (repro.obs.metrics) — this module's former private ``_STATS`` dict,
+# now visible to every exporter.  One labeled instrument per cache key
+# gives :func:`cache_stats` its per-bucket breakdown.
 _HIT_METRIC = "backend.jit.hit"
 _MISS_METRIC = "backend.jit.miss"
-_COMPILE_METRIC = "backend.jit.compile_s"
+
+# XLA compiles happen at a jitted callable's first call with a new
+# shape, not when :func:`jitted` builds it, so they are counted from
+# JAX's own monitoring events: each ``backend_compile_duration`` is one
+# executable, either compiled (``how="compile"``) or loaded from the
+# persistent compilation cache (``how="cache"``, announced by a
+# ``cache_hits`` event just before it on the same thread).
+_XLA_COMPILES = "backend.xla.compiles"
+_XLA_COMPILE_S = "backend.xla.compile_s"
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_COMPILE_TLS = threading.local()
+
+
+def _on_event(event: str, **_kwargs) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _COMPILE_TLS.cache_hit = True
+
+
+def _on_duration(event: str, duration_s: float, **_kwargs) -> None:
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    how = "cache" if getattr(_COMPILE_TLS, "cache_hit", False) \
+        else "compile"
+    _COMPILE_TLS.cache_hit = False
+    metrics.counter(_XLA_COMPILES, how=how).inc()
+    metrics.histogram(_XLA_COMPILE_S, how=how).observe(duration_s)
+
+
+if HAVE_JAX:  # one pair of listeners per process, at first import
+    from jax import monitoring as _monitoring
+
+    _monitoring.register_event_listener(_on_event)
+    _monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def _key_label(key: tuple) -> str:
@@ -245,16 +278,11 @@ def jitted(key: tuple, build: Callable[[], Callable]) -> Callable:
     if fn is not None:
         metrics.counter(_HIT_METRIC, key=label).inc()
         return fn
-    # Build outside the lock (compilation can be slow); a racing
-    # duplicate build is harmless — setdefault keeps the first
-    # insertion and discards the loser, and both callables compute
-    # the same thing.
-    with trace.span("backend.jit.build", key=label):
-        t0 = time.perf_counter()
-        fn = build()
-        dt = time.perf_counter() - t0
+    # Build outside the lock; a racing duplicate build is harmless —
+    # setdefault keeps the first insertion and discards the loser, and
+    # both callables compute the same thing.
+    fn = build()
     metrics.counter(_MISS_METRIC, key=label).inc()
-    metrics.histogram(_COMPILE_METRIC, key=label).observe(dt)
     with _JIT_LOCK:
         _JIT_CACHE.setdefault(key, fn)
         return _JIT_CACHE[key]
@@ -280,10 +308,11 @@ def register_cache_scope(name: str,
 def cache_stats(scope: str = "jit") -> dict:
     """Hit/miss counters and entry count of the substrate's caches.
 
-    ``scope="jit"`` (the default, and the historical return shape)
-    reports the jitted-solver cache, plus a per-bucket breakdown:
-    ``"buckets"`` maps each cache-key label to its ``{"hits", "misses",
-    "compile_s"}`` (compile wall time summed over rebuilds of that key).
+    ``scope="jit"`` (the default) reports the jitted-solver cache, plus
+    a per-bucket breakdown: ``"buckets"`` maps each cache-key label to
+    its ``{"hits", "misses"}``.  ``"xla"`` gives the XLA executables
+    the process compiled and the seconds they took, ``{"compiles",
+    "compile_s"}`` (loads from the persistent cache not included).
     ``scope="all"`` reports every known cache once, keyed by scope name
     (``{"jit": ..., "plan": ...}`` with :mod:`repro.serve` imported) —
     the shape ``/statsz`` and ``repro.obs.report`` consume, with no
@@ -303,24 +332,24 @@ def cache_stats(scope: str = "jit") -> dict:
                 ["jit", "all", *sorted(_SCOPE_PROVIDERS)])
         return provider()
     buckets: dict[str, dict] = {}
-
-    def _bucket(label: str) -> dict:
-        return buckets.setdefault(
-            label, {"hits": 0, "misses": 0, "compile_s": 0.0})
-
-    hits = misses = 0
+    hits = misses = compiles = 0
+    compile_s = 0.0
     for row in metrics.snapshot():
-        label = row["labels"].get("key")
-        if label is None:
-            continue
-        if row["name"] == _HIT_METRIC:
-            _bucket(label)["hits"] = row["value"]
-            hits += row["value"]
-        elif row["name"] == _MISS_METRIC:
-            _bucket(label)["misses"] = row["value"]
-            misses += row["value"]
-        elif row["name"] == _COMPILE_METRIC:
-            _bucket(label)["compile_s"] = row["sum"]
+        if row["name"] == _XLA_COMPILES and \
+                row["labels"] == {"how": "compile"}:
+            compiles = row["value"]
+        elif row["name"] == _XLA_COMPILE_S and \
+                row["labels"] == {"how": "compile"}:
+            compile_s = row["sum"]
+        elif row["name"] in (_HIT_METRIC, _MISS_METRIC):
+            counts = buckets.setdefault(row["labels"]["key"],
+                                        {"hits": 0, "misses": 0})
+            if row["name"] == _HIT_METRIC:
+                counts["hits"] = row["value"]
+                hits += row["value"]
+            else:
+                counts["misses"] = row["value"]
+                misses += row["value"]
     with _JIT_LOCK:
         entries = len(_JIT_CACHE)
     total = hits + misses
@@ -330,6 +359,7 @@ def cache_stats(scope: str = "jit") -> dict:
         "entries": entries,
         "hit_rate": (hits / total) if total else 0.0,
         "buckets": buckets,
+        "xla": {"compiles": compiles, "compile_s": compile_s},
     }
 
 

@@ -347,7 +347,6 @@ def _run_numpy(enc: _Encoded, arch: str, specs, placement, t_max: float,
     end_arr = np.full((B, R, L), np.nan)
     records: list[list[Record]] = [[] for _ in range(B)]
     n_steps = 0
-    trace_on = trace.enabled()  # latched: per-step probes check one bool
 
     def cur(arr):
         return np.take_along_axis(
@@ -424,11 +423,6 @@ def _run_numpy(enc: _Encoded, arch: str, specs, placement, t_max: float,
 
         # -- one Eq. 4–5 solve across every populated (scenario, domain)
         working = (ck == _WORK) & prog[:, None]
-        if trace_on:
-            metrics.histogram("desync.step.active_scenarios").observe(
-                float(prog.sum()))
-            metrics.histogram("desync.step.working_ranks").observe(
-                float(working.sum()))
         rate = np.zeros((B, R))
         if working.any():
             kern_c = cur(enc.kern)
@@ -701,31 +695,37 @@ def _run_jax(enc: _Encoded, arch: str, specs, placement, t_max: float,
     # _PAD items past every plen — both exactly neutral to the loop.
     Bb = backend_mod.bucket(B)
     Lb = backend_mod.bucket(L)
-    kind_p = np.full((Bb, R, Lb), _PAD, dtype=enc.kind.dtype)
-    kind_p[:B, :, :L] = enc.kind
-    qty_p = np.zeros((Bb, R, Lb))
-    qty_p[:B, :, :L] = enc.qty
-    kern_p = np.full((Bb, R, Lb), -1, dtype=enc.kern.dtype)
-    kern_p[:B, :, :L] = enc.kern
-    plen_p = np.zeros((Bb, R), dtype=enc.plen.dtype)
-    plen_p[:B] = enc.plen
-
     runner = backend_mod.jitted(
         ("desync.run_batch", Bb, R, Lb, K, D),
         lambda: _build_jax_runner(Bb, R, Lb, K, D))
     with backend_mod.x64():
-        out = runner(jnp.asarray(kind_p, jnp.int32),
-                     jnp.asarray(qty_p, jnp.float64),
-                     jnp.asarray(kern_p, jnp.int32),
-                     jnp.asarray(plen_p, jnp.int32),
-                     jnp.asarray(dom_of_rank, jnp.int32),
-                     jnp.asarray(f_vec, jnp.float64),
-                     jnp.asarray(bs_vec, jnp.float64),
-                     jnp.float64(t_max), jnp.int64(max_steps))
-        t, pc, start_a, end_a, steps, dead = \
-            tuple(np.asarray(x) for x in out)
-    t, pc, dead = t[:B], pc[:B], dead[:B]
-    start_a, end_a = start_a[:B, :, :L], end_a[:B, :, :L]
+        with trace.span("desync.jax.put"):
+            kind_p = np.full((Bb, R, Lb), _PAD, dtype=enc.kind.dtype)
+            kind_p[:B, :, :L] = enc.kind
+            qty_p = np.zeros((Bb, R, Lb))
+            qty_p[:B, :, :L] = enc.qty
+            kern_p = np.full((Bb, R, Lb), -1, dtype=enc.kern.dtype)
+            kern_p[:B, :, :L] = enc.kern
+            plen_p = np.zeros((Bb, R), dtype=enc.plen.dtype)
+            plen_p[:B] = enc.plen
+            # Waiting for the inputs to arrive keeps their copies under
+            # ``put``; the runner could not start before them anyway.
+            args = jax.block_until_ready((
+                jnp.asarray(kind_p, jnp.int32),
+                jnp.asarray(qty_p, jnp.float64),
+                jnp.asarray(kern_p, jnp.int32),
+                jnp.asarray(plen_p, jnp.int32),
+                jnp.asarray(dom_of_rank, jnp.int32),
+                jnp.asarray(f_vec, jnp.float64),
+                jnp.asarray(bs_vec, jnp.float64),
+                jnp.float64(t_max), jnp.int64(max_steps)))
+        with trace.span("desync.jax.wait"):
+            out = jax.block_until_ready(runner(*args))
+        with trace.span("desync.jax.get"):
+            t, pc, start_a, end_a, steps, dead = \
+                tuple(np.asarray(x) for x in out)
+            t, pc, dead = t[:B], pc[:B], dead[:B]
+            start_a, end_a = start_a[:B, :, :L], end_a[:B, :, :L]
 
     if dead.any() and on_deadlock == "raise":
         b = int(np.nonzero(dead)[0][0])
@@ -741,10 +741,11 @@ def _run_jax(enc: _Encoded, arch: str, specs, placement, t_max: float,
             f"({max_steps}) with scenario {b} unfinished at "
             f"t={t[b]:.6f}s — records would be truncated; use the "
             f"numpy backend or report this as an engine bug")
+    with trace.span("desync.records"):
+        records = _records_from_arrays(enc, start_a, end_a)
     return BatchRunResult(
-        records=_records_from_arrays(enc, start_a, end_a),
-        start=start_a, end=end_a, t_end=t, n_steps=int(steps),
-        backend="jax", failed=dead)
+        records=records, start=start_a, end=end_a, t_end=t,
+        n_steps=int(steps), backend="jax", failed=dead)
 
 
 # --------------------------------------------------------------------------

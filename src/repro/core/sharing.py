@@ -38,7 +38,7 @@ import numpy as np
 from . import backend as backend_mod
 from .backend import HAVE_JAX  # re-export: the probe lives on the substrate
 from .table2 import KernelSpec
-from ..obs import metrics, trace
+from ..obs import trace
 
 if HAVE_JAX:  # pragma: no branch - capability guard, not dispatch
     import jax
@@ -196,15 +196,7 @@ def _fixedpoint_u_np(n, f, p0_factor):
         below = r < 0
         lo = np.where(below, mid, lo)
         hi = np.where(below, hi, mid)
-    u = 0.5 * (lo + hi)
-    if trace.enabled() and u.size:
-        # Diagnostics only — one extra S(u) evaluation, never on the
-        # untraced path, and the returned u is untouched either way.
-        resid = np.max(np.abs(u - np.minimum(1.0, n * f / (1.0 + c * u))))
-        metrics.counter("sharing.fp.solves").inc()
-        metrics.counter("sharing.fp.bisect_iters").inc(_FP_BISECT_ITERS)
-        metrics.histogram("sharing.fp.residual").observe(float(resid))
-    return u
+    return 0.5 * (lo + hi)
 
 
 def utilization_curve(n, f, *, mode: str = "recursion",
@@ -536,14 +528,19 @@ if HAVE_JAX:
             ("sharing.solve_batch", mode, Bb, G, n_max_b),
             lambda: _build_jax_solver(mode, n_max_b))
         with backend_mod.x64():
-            out = solver(
-                jnp.asarray(backend_mod.pad_rows(n, Bb), jnp.float64),
-                jnp.asarray(backend_mod.pad_rows(
-                    np.asarray(f, dtype=np.float64), Bb), jnp.float64),
-                jnp.asarray(backend_mod.pad_rows(
-                    np.asarray(bs, dtype=np.float64), Bb), jnp.float64),
-                jnp.float64(aux))
-        return tuple(np.asarray(x)[:B] for x in out)
+            # The solver cannot start before its inputs arrive, so
+            # waiting for them here costs nothing and keeps the copies
+            # in under ``put`` rather than ``wait``.
+            with trace.span("sharing.jax.put"):
+                args = jax.block_until_ready([jnp.asarray(
+                    backend_mod.pad_rows(np.asarray(a, dtype=np.float64),
+                                         Bb), jnp.float64)
+                    for a in (n, f, bs)])
+            with trace.span("sharing.jax.wait"):
+                out = jax.block_until_ready(
+                    solver(*args, jnp.float64(aux)))
+            with trace.span("sharing.jax.get"):
+                return tuple(np.asarray(x)[:B] for x in out)
 
 
 @dataclasses.dataclass(frozen=True)

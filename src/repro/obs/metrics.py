@@ -17,8 +17,8 @@ backends) as *labels*, never baked into the name::
     from repro.obs import metrics
 
     metrics.counter("backend.jit.miss", key="sharing.solve_batch").inc()
-    metrics.gauge("sharing.fp.residual").set(3.2e-13)
-    metrics.histogram("backend.jit.compile_s").observe(0.41)
+    metrics.gauge("serve.queue.depth").set(12)
+    metrics.histogram("backend.xla.compile_s", how="compile").observe(0.41)
 
 Instruments are get-or-create on every call — handles looked up in hot
 paths stay valid, but after :func:`reset` a cached handle is orphaned

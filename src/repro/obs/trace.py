@@ -24,6 +24,11 @@ Design constraints (in priority order):
    monotonic ``perf_counter_ns`` start, duration, thread id, and nest
    depth; exporters rebuild the parent/child tree from (tid, depth,
    time) alone — probes never pass span handles around.
+4. **One clock with the device.**  Once JAX is loaded, an enabled span
+   also enters a ``jax.profiler.TraceAnnotation`` of its name (not its
+   attributes), so a profiler session stamps it on the host plane of
+   the same timeline as the device's operations.  This module never
+   imports JAX itself.
 
 Enable via ``REPRO_TRACE=1`` in the environment (which also registers
 an at-exit export, see :mod:`repro.obs.export`) or programmatically
@@ -39,6 +44,7 @@ from __future__ import annotations
 
 import functools
 import os
+import sys
 import threading
 import time
 
@@ -169,13 +175,21 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "_t0", "_depth")
+    __slots__ = ("name", "attrs", "_t0", "_depth", "_annotation")
 
     def __init__(self, name: str, attrs: dict | None):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
+        # Looked up per span, so JAX loaded after this module still
+        # gets the annotations; without JAX there is no profiler.
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            self._annotation = None
+        else:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         depth = getattr(_TLS, "depth", 0)
         _TLS.depth = depth + 1
         self._depth = depth
@@ -197,6 +211,8 @@ class _Span:
             self.set(error=exc_type.__name__)
         BUFFER.append(("span", self.name, self._t0, t1 - self._t0,
                        threading.get_ident(), self._depth, self.attrs))
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 

@@ -283,9 +283,9 @@ def test_cache_stats_scope_selector():
     cache = PlanCache()
     cache.get_or_build(("x",), lambda: "x", label="L")
     cache.get_or_build(("x",), lambda: "x", label="L")
-    jit = backend.cache_stats()       # default: the historical shape
+    jit = backend.cache_stats()       # default: the jit cache
     assert set(jit) == {"hits", "misses", "entries", "hit_rate",
-                        "buckets"}
+                        "buckets", "xla"}
     plan = backend.cache_stats(scope="plan")
     assert plan["hits"] == 1 and plan["misses"] == 1
     assert plan["buckets"]["L"]["hits"] == 1
