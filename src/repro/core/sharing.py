@@ -29,8 +29,10 @@ Eqs. 4–5 (they contribute nothing to any sum).
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
+import os
 from typing import Sequence
 
 import numpy as np
@@ -38,7 +40,7 @@ import numpy as np
 from . import backend as backend_mod
 from .backend import HAVE_JAX  # re-export: the probe lives on the substrate
 from .table2 import KernelSpec
-from ..obs import trace
+from ..obs import metrics, trace
 
 if HAVE_JAX:  # pragma: no branch - capability guard, not dispatch
     import jax
@@ -182,6 +184,12 @@ UTILIZATION_MODES = ("queue", "recursion", "fixedpoint")
 #: [0, 1] put the bracket below float64 resolution, so the numpy and jax
 #: forward passes agree bitwise.
 _FP_BISECT_ITERS = 60
+
+#: The jax solve's results come back in pieces of about this many bytes,
+#: copied concurrently.  From a TPU v5e one float64 array came back at
+#: 0.26–0.33 GB/s whatever its layout; the 33.5 MB of a 2^19-row solve
+#: in 2 MiB pieces in 23 ms, against 25–27 ms in pieces of 1 or 4 MiB.
+_FETCH_PIECE_BYTES = 2 << 20
 
 
 def _fixedpoint_u_np(n, f, p0_factor):
@@ -477,13 +485,74 @@ if HAVE_JAX:
         bw = alphas * util * b
         return b, alphas, util, bw
 
-    def _build_jax_solver(mode: str, n_max: int):
+    def _fetch_pieces(values: int) -> int:
+        """How many pieces a flat float64 result of ``values`` comes back
+        in: one per :data:`_FETCH_PIECE_BYTES` begun."""
+        return max(1, -(-8 * values // _FETCH_PIECE_BYTES))
+
+    def _build_jax_solver(mode: str, n_max: int,
+                          rows: tuple[int, int] | None = None):
         """Jitted vmap of the single-scenario solver for one shape
-        bucket; registered in the substrate's process-wide cache."""
+        bucket; registered in the substrate's process-wide cache.
+
+        The program takes ``n, f, bs`` as ``(Bb, G)`` arrays, or, given
+        ``rows`` = ``(Bb, G)``, flat as ``(Bb·G,)`` and laid out as rows
+        on the device: a TPU runtime copies a flat float64 array in as
+        it is, but splits a ``(rows, G)`` one into tiles by a host
+        transpose per 128 rows or so (110,595 of them for the three
+        inputs of a 2^19-row solve, each an event in a profiler trace).
+        It returns its four outputs ``(b, alphas, util, bw)`` flat, each
+        as a tuple of :func:`_fetch_pieces` consecutive pieces, which
+        :func:`_fetch_outputs` copies back concurrently."""
         vmapped = jax.vmap(
             functools.partial(_solve_single_jax, mode=mode, n_max=n_max),
             in_axes=(0, 0, 0, None))
-        return jax.jit(vmapped)
+
+        def solve(n, f, bs, p0_aux):
+            if rows is not None:
+                n, f, bs = (x.reshape(rows) for x in (n, f, bs))
+            return tuple(
+                tuple(jnp.array_split(x, _fetch_pieces(x.size)))
+                for x in (a.ravel() for a in vmapped(n, f, bs, p0_aux)))
+
+        # The program takes its name from this function: keep the
+        # solver's, which device traces are searched for.
+        solve.__name__ = solve.__qualname__ = _solve_single_jax.__name__
+        return jax.jit(solve)
+
+    @functools.cache
+    def _fetch_pool() -> concurrent.futures.ThreadPoolExecutor:
+        """The threads that land the solve's pieces: made on first use,
+        kept for the process (a forked child makes its own)."""
+        return concurrent.futures.ThreadPoolExecutor(
+            os.cpu_count() or 1, thread_name_prefix="sharing-fetch")
+
+    os.register_at_fork(after_in_child=_fetch_pool.cache_clear)
+
+    def _land(job) -> None:
+        into, piece = job
+        into[...] = np.asarray(piece)
+
+    def _fetch_outputs(outputs) -> list[np.ndarray]:
+        """Each flat output on the host, from its pieces on the device.
+
+        Every piece's copy starts at once; the pieces of each output
+        land in their places in one buffer of its own, on a pool of
+        threads, as they arrive: the waits and the copies release the
+        interpreter lock, so the slow float64 copies overlap."""
+        for pieces in outputs:
+            for piece in pieces:
+                piece.copy_to_host_async()
+        flat, jobs = [], []
+        for pieces in outputs:
+            buf = np.empty(sum(p.shape[0] for p in pieces), np.float64)
+            flat.append(buf)
+            start = 0
+            for piece in pieces:
+                jobs.append((buf[start:start + piece.shape[0]], piece))
+                start += piece.shape[0]
+        list(_fetch_pool().map(_land, jobs))
+        return flat
 
     def _build_jax_grad_solver(mode: str, n_max: int, beta: float | None,
                                argnums: tuple[int, ...]):
@@ -526,7 +595,7 @@ if HAVE_JAX:
         Bb = backend_mod.bucket(B)
         solver = backend_mod.jitted(
             ("sharing.solve_batch", mode, Bb, G, n_max_b),
-            lambda: _build_jax_solver(mode, n_max_b))
+            lambda: _build_jax_solver(mode, n_max_b, (Bb, G)))
         with backend_mod.x64():
             # The solver cannot start before its inputs arrive, so
             # waiting for them here costs nothing and keeps the copies
@@ -534,13 +603,17 @@ if HAVE_JAX:
             with trace.span("sharing.jax.put"):
                 args = jax.block_until_ready([jnp.asarray(
                     backend_mod.pad_rows(np.asarray(a, dtype=np.float64),
-                                         Bb), jnp.float64)
+                                         Bb).reshape(-1), jnp.float64)
                     for a in (n, f, bs)])
             with trace.span("sharing.jax.wait"):
                 out = jax.block_until_ready(
                     solver(*args, jnp.float64(aux)))
             with trace.span("sharing.jax.get"):
-                return tuple(np.asarray(x)[:B] for x in out)
+                b, alphas, util, bw = _fetch_outputs(out)
+                metrics.counter("sharing.jax.get_bytes").inc(
+                    b.nbytes + alphas.nbytes + util.nbytes + bw.nbytes)
+                return (b[:B], alphas.reshape(Bb, G)[:B], util[:B],
+                        bw.reshape(Bb, G)[:B])
 
 
 @dataclasses.dataclass(frozen=True)

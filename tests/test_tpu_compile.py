@@ -101,6 +101,15 @@ def test_f64_sharing_solver_compiles(compile_hlo, mode, n_max):
     assert "f64" in hlo
 
 
+def test_f64_sharing_solver_compiles_with_flat_inputs(compile_hlo):
+    G = 4
+    solver = sharing._build_jax_solver("recursion", 16, (SOLVER_B, G))
+    flat = ((SOLVER_B * G,), jnp.float64)
+    with backend.x64():
+        hlo = compile_hlo(solver, flat, flat, flat, ((), jnp.float64))
+    assert "f64" in hlo
+
+
 def test_f64_desync_runner_compiles(compile_hlo):
     B, R, L, K, D = DESYNC
     runner = desync_batch._build_jax_runner(B, R, L, K, D)
