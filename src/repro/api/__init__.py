@@ -40,7 +40,7 @@ facade dispatches to, and facade results are bit-for-bit theirs.
 from .engine import JAX_BATCH_CUTOFF, predict, simulate
 from .plan import (BatchPlan, PlacedBatchPlan, PlacedPlan, Plan,
                    ScalarPlan, SimulatePlan, compile, derive_member_seed,
-                   infer_verb, structure_key)
+                   infer_verb, node_key, structure_key)
 from .registry import (PROVENANCES, ResolvedSpec, from_loop_features,
                        from_static_analysis, known_archs, known_kernels,
                        resolve, suggest, unknown_key_error,
@@ -56,7 +56,7 @@ __all__ = [
     "predict", "simulate", "JAX_BATCH_CUTOFF",
     "compile", "Plan", "ScalarPlan", "PlacedPlan", "BatchPlan",
     "PlacedBatchPlan", "SimulatePlan", "derive_member_seed",
-    "infer_verb", "structure_key",
+    "infer_verb", "node_key", "structure_key",
     "Scenario", "ScenarioBatch", "RunSpec", "StepSpec", "Noise",
     "DEFAULT_WORK_BYTES",
     "resolve", "ResolvedSpec", "from_loop_features",

@@ -493,7 +493,9 @@ class PlacedBatchPlan(Plan):
         masked out regardless of what the broadcast writes there).
         ``placement`` swaps the whole placement batch — a sequence of
         B placement lists (:class:`repro.core.topology.Placed`) on the
-        plan's topology, re-packed without re-tracing the scenarios.
+        plan's topology, re-packed without re-tracing the scenarios onto
+        at least the plan's K lanes per domain, so a batch of fewer
+        groups per domain runs the solver the plan compiled for.
         ``backend``/``jax_cutoff``/``chunk`` re-resolve dispatch for
         this run only.
         """
@@ -512,8 +514,9 @@ class PlacedBatchPlan(Plan):
                     f"placement gives {len(placement)} scenarios for the "
                     f"plan's {len(self)}")
             with trace.span("api.plan.pack"):
-                grid = topology_mod.pack_placed(self.topo, placement,
-                                                strict=self.strict)
+                grid = topology_mod.pack_placed(
+                    self.topo, placement, strict=self.strict,
+                    lanes=self.grid.n.shape[2])
         n_arr = _swap_array(grid.n, cores, "cores")
         f_arr = _swap_array(grid.f, f, "f")
         bs_arr = _swap_array(grid.bs, b_s, "b_s")
@@ -680,6 +683,17 @@ def _options_signature(sc: "Scenario") -> tuple:
             sc.jax_cutoff, sc.chunk, sc.strict)
 
 
+def node_key(scenario: "Scenario", *, verb: str | None = None) -> tuple:
+    """The leading part of :func:`structure_key`: ``(verb, arch,
+    options, topology fingerprint)``, which a placed plan's
+    ``placement=`` swap leaves fixed, so every placed prediction with
+    an equal node key can run on one placed plan."""
+    if verb is None:
+        verb = infer_verb(scenario)
+    return (verb, scenario.arch, _options_signature(scenario),
+            _topology_fingerprint(scenario.topo))
+
+
 def structure_key(scenario: "Scenario | ScenarioBatch", *,
                   verb: str | None = None) -> tuple:
     """A hashable fingerprint of everything :func:`compile` *traces* —
@@ -715,12 +729,11 @@ def structure_key(scenario: "Scenario | ScenarioBatch", *,
     if verb not in ("predict", "simulate"):
         raise ValueError(
             f"unknown verb {verb!r}; expected 'predict' or 'simulate'")
-    opts = _options_signature(sc)
-    topo = _topology_fingerprint(sc.topo)
+    node = node_key(sc, verb=verb)
     if verb == "predict":
         rows = tuple((r.tag, r.resolved.name, r.resolved.provenance,
                       r.domain) for r in sc.runs)
-        return ("predict", sc.arch, opts, topo, rows)
+        return node + (rows,)
     runs = tuple(
         (r.tag, r.resolved.name, r.resolved.provenance, r.domain,
          int(r.n), float(r.bytes), float(r.spec.f[sc.arch]),
@@ -737,8 +750,8 @@ def structure_key(scenario: "Scenario | ScenarioBatch", *,
     noise = None if sc.noise is None else (
         sc.noise.exp_mean_s, sc.noise.seed, sc.noise.ensemble,
         sc.noise.tag)
-    return ("simulate", sc.arch, opts, topo, runs, steps, noise,
-            sc.n_ranks, sc.rank_domains, sc.t_max)
+    return node + (runs, steps, noise, sc.n_ranks, sc.rank_domains,
+                   sc.t_max)
 
 
 # ---------------------------------------------------------------------------

@@ -185,6 +185,12 @@ UTILIZATION_MODES = ("queue", "recursion", "fixedpoint")
 #: forward passes agree bitwise.
 _FP_BISECT_ITERS = 60
 
+#: Least recursion bound the jitted solver compiles its loop for: steps
+#: past a row's thread count leave its utilization as it is, so a short
+#: loop changes no bit, and every thread count up to it shares one
+#: program (one per row and group bucket on an 8-core domain).
+MIN_RECURSION_BOUND = 8
+
 #: The jax solve's results come back in pieces of about this many bytes,
 #: copied concurrently.  From a TPU v5e one float64 array came back at
 #: 0.26–0.33 GB/s whatever its layout; the 33.5 MB of a 2^19-row solve
@@ -591,7 +597,8 @@ if HAVE_JAX:
         # other modes share one executable per (B, G) bucket.
         n_max = int(n.sum(axis=-1).max()) if (n.size and mode == "recursion") \
             else 0
-        n_max_b = backend_mod.bucket(n_max) if n_max else 0
+        n_max_b = backend_mod.bucket(n_max, minimum=MIN_RECURSION_BOUND) \
+            if n_max else 0
         Bb = backend_mod.bucket(B)
         solver = backend_mod.jitted(
             ("sharing.solve_batch", mode, Bb, G, n_max_b),

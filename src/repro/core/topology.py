@@ -23,6 +23,7 @@ vectorized call regardless of how many domains are populated.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterable, Mapping, Sequence
 
 import numpy as np
@@ -83,9 +84,10 @@ class Topology:
     def name(self) -> str:
         return self.root.name
 
-    @property
+    @functools.cached_property
     def domains(self) -> tuple[ContentionDomain, ...]:
-        """All leaves, depth-first (stable order used for batching)."""
+        """All leaves, depth-first (stable order used for batching);
+        walked once per topology, which is immutable."""
         out: list[ContentionDomain] = []
 
         def walk(node: TopologyNode | ContentionDomain) -> None:
@@ -98,9 +100,14 @@ class Topology:
         walk(self.root)
         return tuple(out)
 
-    @property
+    @functools.cached_property
     def domain_names(self) -> tuple[str, ...]:
         return tuple(d.name for d in self.domains)
+
+    @functools.cached_property
+    def domain_index(self) -> dict[str, int]:
+        """Each domain's position in :attr:`domains`, by name."""
+        return {name: i for i, name in enumerate(self.domain_names)}
 
     def domain(self, name: str) -> ContentionDomain:
         for d in self.domains:
@@ -173,22 +180,9 @@ def predict_placed(topology: Topology, placements: Sequence[Placed], *,
     """
     placements = tuple(placements)
     names = topology.domain_names
-    per_domain: dict[str, list[tuple[int, Group]]] = {n: [] for n in names}
-    for idx, p in enumerate(placements):
-        if p.domain not in per_domain:
-            raise KeyError(
-                f"placement {idx} names unknown domain {p.domain!r}; "
-                f"available: {list(names)}")
-        per_domain[p.domain].append((idx, p.group))
-
-    if strict:
-        for name in names:
-            used = sum(g.n for _, g in per_domain[name])
-            cap = topology.domain(name).n_cores
-            if used > cap:
-                raise ValueError(
-                    f"domain {name!r} overcommitted: {used} threads placed "
-                    f"on {cap} cores (pass strict=False to allow)")
+    by_index = check_placement(topology, placements, strict=strict)
+    per_domain: dict[str, list[tuple[int, Group]]] = {
+        n: by_index.get(i, []) for i, n in enumerate(names)}
 
     populated = [n for n in names if per_domain[n]]
     by_domain: dict[str, SharePrediction] = {}
@@ -247,42 +241,63 @@ class PlacedGrid:
         return len(self.placements)
 
 
+def check_placement(topology: Topology, placements: Sequence[Placed], *,
+                    strict: bool = True
+                    ) -> dict[int, list[tuple[int, Group]]]:
+    """One scenario's groups by domain, checked: ``{domain index:
+    [(placement index, group), ...]}`` in placement order, the order
+    every solver packs a domain's lanes in.
+
+    A placement on a domain the topology lacks raises ``KeyError``;
+    under ``strict``, a domain given more threads than it has cores
+    raises ``ValueError``.  The one rule of a valid placement, for
+    :func:`predict_placed`, :func:`pack_placed` and the service's
+    per-request checks.
+    """
+    index = topology.domain_index
+    per_domain: dict[int, list[tuple[int, Group]]] = {}
+    for idx, p in enumerate(placements):
+        d = index.get(p.domain)
+        if d is None:
+            raise KeyError(
+                f"placement {idx} names unknown domain {p.domain!r}; "
+                f"available: {list(index)}")
+        per_domain.setdefault(d, []).append((idx, p.group))
+    if strict:
+        domains = topology.domains
+        for d in sorted(per_domain):
+            used = sum(g.n for _, g in per_domain[d])
+            if used > domains[d].n_cores:
+                raise ValueError(
+                    f"domain {domains[d].name!r} overcommitted: {used:g} "
+                    f"threads placed on {domains[d].n_cores} cores (pass "
+                    f"strict=False to allow)")
+    return per_domain
+
+
 def pack_placed(topology: Topology,
                 placements_batch: Sequence[Sequence[Placed]], *,
-                strict: bool = True) -> PlacedGrid:
+                strict: bool = True, lanes: int = 1) -> PlacedGrid:
     """Pad B heterogeneous placements to one occupancy-masked grid.
 
     Groups keep their placement order within each domain (the same order
     :func:`predict_placed` packs them in, so grid solves are bit-for-bit
-    comparable).  ``strict=True`` applies the same unknown-domain and
-    overcommit checks as :func:`predict_placed`, per scenario.
+    comparable).  Each scenario is checked by :func:`check_placement`
+    (overcommits only under ``strict``).  The grid
+    has at least ``lanes`` lanes per domain (trailing empty lanes are
+    neutral), so batches of fewer groups can keep a solver's shape.
     """
     placements_batch = tuple(tuple(p) for p in placements_batch)
-    names = topology.domain_names
-    dom_index = {n: i for i, n in enumerate(names)}
-    caps = {n: topology.domain(n).n_cores for n in names}
-    B, D = len(placements_batch), len(names)
+    B, D = len(placements_batch), len(topology.domains)
 
     per_scenario: list[dict[int, list[tuple[int, Group]]]] = []
-    K = 1
+    K = max(int(lanes), 1)
     for b, placements in enumerate(placements_batch):
-        per_domain: dict[int, list[tuple[int, Group]]] = {}
-        used = dict.fromkeys(names, 0.0)
-        for idx, p in enumerate(placements):
-            if p.domain not in dom_index:
-                raise KeyError(
-                    f"scenario {b}: placement {idx} names unknown domain "
-                    f"{p.domain!r}; available: {list(names)}")
-            per_domain.setdefault(dom_index[p.domain], []).append(
-                (idx, p.group))
-            used[p.domain] += p.group.n
-        if strict:
-            for name in names:
-                if used[name] > caps[name]:
-                    raise ValueError(
-                        f"scenario {b}: domain {name!r} overcommitted: "
-                        f"{used[name]:g} threads placed on {caps[name]} "
-                        f"cores (pass strict=False to allow)")
+        try:
+            per_domain = check_placement(topology, placements,
+                                         strict=strict)
+        except (KeyError, ValueError) as e:
+            raise type(e)(f"scenario {b}: {e.args[0]}") from None
         per_scenario.append(per_domain)
         K = max(K, *(len(v) for v in per_domain.values()), 1)
 

@@ -85,23 +85,40 @@ class PlanCache:
             return self._entries[key]
 
     def warmup(self, scenario, *, verb: str | None = None,
-               buckets=(1,)) -> int:
+               buckets=(1,), lanes=None) -> int:
         """Precompile the plans that will serve ``scenario``'s structure
         at each batch bucket (each rounded up to a power of two), so the
         first live tick hits.  Returns the number of entries compiled
-        (cached buckets count zero)."""
+        (cached buckets count zero).
+
+        A placed prediction's plans serve its whole node: one per row
+        bucket and lane bucket (``lanes``, default the scenario's own).
+        Each of them that solves on the jax backend is also run once
+        per recursion bound the node can reach, so that no live tick
+        compiles a solver program either."""
         from .. import api
         if verb is None:
             verb = api.infer_verb(scenario)
+        sig = keys_mod.group_key(scenario, verb)
+        placed = keys_mod.is_placed(scenario, verb)
+        if placed and lanes is None:
+            lanes = (keys_mod.placement(scenario)[1],)
         built = 0
         for b in sorted({backend_mod.bucket(b) for b in buckets}):
-            sig = keys_mod.group_key(scenario, verb)
-            key, rows = keys_mod.plan_entry(verb, sig, b)
-            before = self._misses
-            self.get_or_build(
-                key, lambda: keys_mod.compile_group([scenario], verb, rows),
-                label=keys_mod.key_label(verb, scenario, rows))
-            built += self._misses - before
+            for k in (sorted({keys_mod.lane_bucket(k, scenario.topo)
+                              for k in lanes})
+                      if placed else (None,)):
+                key, rows = keys_mod.plan_entry(verb, sig, b, k)
+                before = self._misses
+                plan = self.get_or_build(
+                    key, lambda: keys_mod.compile_group([scenario], verb,
+                                                        rows, k),
+                    label=keys_mod.key_label(verb, scenario, rows, k))
+                built += self._misses - before
+                if placed and plan.engine == "jax":
+                    for batch in keys_mod.warm_placements(scenario, rows,
+                                                          k):
+                        plan.run(placement=batch)
             if verb == "simulate":
                 break  # bucket-free: one entry serves every batch size
         return built

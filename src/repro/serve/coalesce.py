@@ -19,17 +19,20 @@ unresolved.
 
 Everything here is socket-free: tests drive ``submit``/``close``
 directly under ``asyncio.run``.  Solves run inline on the event loop
-(a deliberate single-process design — the solve *is* the service;
-see docs/serving.md for the scaling discussion).
+(a deliberate single-process design — the host work around a solve is
+GIL-bound, and a worker thread for device ticks measured slower on the
+chip; see docs/serving.md for the scaling discussion).
 """
 
 from __future__ import annotations
 
 import asyncio
 import dataclasses
+import itertools
 import time
 import weakref
 from collections import deque
+from typing import Iterable
 
 from .. import api
 from ..obs import metrics, trace
@@ -77,6 +80,16 @@ class ServeConfig:
     #: LRU capacity of the plan cache the server builds when the caller
     #: does not pass one.
     cache_entries: int = 128
+
+
+def _engine_error(e: Exception) -> ServeError:
+    """An engine failure, reported to its requests; serving goes on."""
+    return ServeError(f"{type(e).__name__}: {e}")
+
+
+#: Requests a tick hands their results at a time (:meth:`Coalescer._release`).
+#: A placed result costs about 0.1 ms to build, so a piece is tens of ms.
+_PIECE = 1024
 
 
 @dataclasses.dataclass
@@ -129,6 +142,7 @@ class Coalescer:
             v: metrics.histogram("serve.latency_s", verb=v)
             for v in ("predict", "simulate")}
         self._m_batch = metrics.histogram("serve.tick.batch")
+        self._m_queue = metrics.histogram("serve.queue_s")
         self._m_depth = metrics.gauge("serve.queue.depth")
         # Structure-key memo for resubmitted scenario *objects* (the
         # embedded-client pattern: a calibration loop holds scenarios
@@ -239,10 +253,10 @@ class Coalescer:
                 batch.append(self._pending.popleft())
             self._gauge()
             self._ticks += 1
-            self._process(batch)
+            await self._process(batch)
             await asyncio.sleep(0)  # yield between solves under load
 
-    def _process(self, batch: "list[_Pending]") -> None:
+    async def _process(self, batch: "list[_Pending]") -> None:
         now = time.monotonic()
         groups: dict[tuple, list[_Pending]] = {}
         for p in batch:
@@ -259,28 +273,63 @@ class Coalescer:
                 p.scenario, p.verb),), []).append(p)
         if not groups:
             return
+        solved = []
         with trace.span("serve.coalesce", tick=self._ticks,
                         n=sum(len(g) for g in groups.values()),
                         groups=len(groups)):
             for (verb, sig), plist in groups.items():
                 self._m_batch.observe(len(plist))
                 try:
-                    results = self._solve(verb, sig, plist)
+                    results = self._timed_solve(verb, sig, plist)
                 except ServeError as e:
                     self._fail(plist, e)
                 except (ValueError, TypeError, KeyError) as e:
                     self._fail(plist, BadRequest(str(e)))
                 except Exception as e:  # engine failure: report, keep serving
-                    self._fail(plist, ServeError(
-                        f"{type(e).__name__}: {e}"))
+                    self._fail(plist, _engine_error(e))
                 else:
-                    done = time.monotonic()
-                    latency = self._m_latency[verb]
-                    for p, result in zip(plist, results):
-                        if not p.future.done():
-                            p.future.set_result(result)
-                        latency.observe(done - p.t_submit)
-                        self.counts["completed"] += 1
+                    solved.append((verb, plist, iter(results)))
+        for verb, plist, results in solved:
+            await self._release(verb, plist, results)
+
+    async def _release(self, verb: str, plist: "list[_Pending]",
+                       results) -> None:
+        """Hand a solved group's requests their results, ``_PIECE`` at a
+        time, yielding to the loop between pieces: the streams of a
+        large tick write its first answers while its later results are
+        still being built."""
+        latency = self._m_latency[verb]
+        for at in range(0, len(plist), _PIECE):
+            piece = plist[at:at + _PIECE]
+            try:
+                got = list(itertools.islice(results, len(piece)))
+            except Exception as e:  # a result failed to build
+                self._fail(plist[at:], _engine_error(e))
+                return
+            done = time.monotonic()
+            for p, result in zip(piece, got):
+                if isinstance(result, ServeError):
+                    self._fail([p], result)   # it failed alone
+                    continue
+                if not p.future.done():
+                    p.future.set_result(result)
+                latency.observe(done - p.t_submit)
+                self.counts["completed"] += 1
+            await asyncio.sleep(0)
+
+    def _timed_solve(self, verb: str, sig: tuple,
+                     plist: "list[_Pending]") -> Iterable:
+        """One group's solve under its ``serve.solve`` span (labelled
+        with the backend its plan ran on), after recording how long
+        each request queued for it."""
+        start = time.monotonic()
+        queued = self._m_queue
+        for p in plist:
+            queued.observe(start - p.t_submit)
+        with trace.span("serve.solve", n=len(plist)) as sp:
+            results, engine = self._solve(verb, sig, plist)
+            sp.set(backend=engine)
+            return results
 
     def _group_key(self, sc, verb: str) -> tuple:
         memo = self._key_memo
@@ -307,9 +356,11 @@ class Coalescer:
     # -- the batched solve --------------------------------------------------
 
     def _solve(self, verb: str, sig: tuple,
-               plist: "list[_Pending]") -> list:
+               plist: "list[_Pending]") -> tuple[Iterable, str]:
         scens = [p.scenario for p in plist]
         first = scens[0]
+        if keys_mod.is_placed(first, verb):
+            return self._solve_placed(sig, scens)
         key, rows = keys_mod.plan_entry(verb, sig, len(scens))
         label = keys_mod.key_label(verb, first, rows)
         plan = self.cache.get_or_build(
@@ -317,15 +368,46 @@ class Coalescer:
             label=label)
         if verb == "simulate":
             # Identical structure (numbers included) → one shared run.
-            return [plan.run()] * len(scens)
-        if first.is_placed or first.topo is not None:
-            pred = plan.run(
-                placement=keys_mod.padded_placements(scens, rows))
-        else:
-            n, f, bs = keys_mod.swap_arrays(scens, rows, plan.n.shape[1])
-            pred = plan.run(cores=n, f=f, b_s=bs)
-            return pred.rows(len(scens))   # bulk fan-out (one tolist pass)
-        return [pred[i] for i in range(len(scens))]
+            return [plan.run()] * len(scens), plan.engine
+        n, f, bs = keys_mod.swap_arrays(scens, rows, plan.n.shape[1])
+        pred = plan.run(cores=n, f=f, b_s=bs)
+        # bulk fan-out (one tolist pass)
+        return pred.rows(len(scens)), plan.engine
+
+    def _solve_placed(self, sig: tuple, scens: "list[api.Scenario]"
+                      ) -> tuple[Iterable, str]:
+        """Every placed request of one node in one solve: the node's
+        plan for the tick's row and lane buckets, with the tick's
+        placements swapped in.  A request whose placement is invalid
+        fails alone (its entry is the error).  The results come lazily,
+        in request order."""
+        results: list = [None] * len(scens)
+        live, placed, most = [], [], 1
+        for i, sc in enumerate(scens):
+            try:
+                pl, k = keys_mod.placement(sc)
+            except (KeyError, ValueError) as e:
+                results[i] = BadRequest(str(e.args[0] if e.args else e))
+            else:
+                live.append(i)
+                placed.append(pl)
+                most = max(most, k)
+        if not live:
+            return results, "none"
+        first = scens[live[0]]
+        lanes = keys_mod.lane_bucket(most, first.topo)
+        key, rows = keys_mod.plan_entry("predict", sig, len(live), lanes)
+        plan = self.cache.get_or_build(
+            key, lambda: keys_mod.compile_group([first], "predict", rows,
+                                                lanes),
+            label=keys_mod.key_label("predict", first, rows, lanes))
+        pred = plan.run(placement=keys_mod.padded_placements(placed, rows))
+        pred = dataclasses.replace(pred, provenance=tuple(
+            scens[i].provenance for i in live))
+        # Each live request's result is built as it is handed out.
+        row = iter(range(len(live)))
+        return (r if r is not None else pred[next(row)]
+                for r in results), plan.engine
 
     # -- introspection ------------------------------------------------------
 

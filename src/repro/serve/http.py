@@ -18,11 +18,15 @@ into the same ticks.
 from __future__ import annotations
 
 import asyncio
+import collections
+import contextlib
+import gc
+import itertools
 import json
 import time
 
 from ..core import backend as backend_mod
-from ..obs import metrics
+from ..obs import metrics, trace
 from .cache import PlanCache
 from .coalesce import Coalescer, ServeConfig, ServeError
 from . import protocol
@@ -39,6 +43,34 @@ _STATUS_TEXT = {200: "OK", 400: "Bad Request", 404: "Not Found",
                 504: "Gateway Timeout"}
 
 
+#: Most request lines of one post parsed and queued past its first
+#: unanswered line; the rest follow, in pieces of half as many, as the
+#: answers go out.  A post of 16,384 lines so keeps its parsing next to
+#: its answers and holds few parsed requests at a time; eight such
+#: posts still fill ticks of up to 16,384 lines.
+_PARSE_AHEAD = 2048
+
+#: Seconds between the server's own full garbage collections, which
+#: replace Python's automatic ones while it serves.  Under a deep queue
+#: every queued request's objects reach the oldest generation, and the
+#: automatic schedule walks them all every second or two.
+_GC_PERIOD_S = 10.0
+
+
+async def _collect_garbage(period_s: float) -> None:
+    """Collect the oldest generation every ``period_s`` seconds, and
+    only then, until cancelled; Python's thresholds come back after."""
+    saved = gc.get_threshold()
+    gc.set_threshold(saved[0], saved[1], 2**31 - 1)
+    try:
+        while True:
+            await asyncio.sleep(period_s)
+            with trace.span("serve.gc"):
+                gc.collect()
+    finally:
+        gc.set_threshold(*saved)
+
+
 class App:
     """The server: one coalescer + plan cache behind an asyncio
     listener.  Socket-free layers stay reachable (``app.coalescer``,
@@ -52,6 +84,7 @@ class App:
                       else PlanCache(self.config.cache_entries))
         self.coalescer = Coalescer(self.config, cache=self.cache)
         self._server: asyncio.base_events.Server | None = None
+        self._gc: asyncio.Task | None = None
         self._t0 = time.monotonic()
 
     # -- lifecycle ----------------------------------------------------------
@@ -60,6 +93,9 @@ class App:
         """Bind and listen; returns the bound port (useful with
         ``port=0``)."""
         self.coalescer.start()
+        if self._gc is None:
+            self._gc = asyncio.get_running_loop().create_task(
+                _collect_garbage(_GC_PERIOD_S), name="repro.serve.gc")
         self._server = await asyncio.start_server(
             self._client, host=host, port=port)
         return self._server.sockets[0].getsockname()[1]
@@ -71,6 +107,11 @@ class App:
             await self._server.wait_closed()
             self._server = None
         await self.coalescer.close(drain=drain)
+        if self._gc is not None:
+            self._gc.cancel()
+            with contextlib.suppress(asyncio.CancelledError):
+                await self._gc
+            self._gc = None
 
     async def serve_forever(self) -> None:
         assert self._server is not None, "call start() first"
@@ -166,29 +207,49 @@ class App:
         return await reader.readexactly(length), None
 
     async def _stream(self, writer, body: bytes, verb: str | None) -> None:
-        """Submit every request line, then stream the response lines in
+        """Parse and submit the request lines, at most ``_PARSE_AHEAD``
+        past the first unanswered one, and stream the response lines in
         request order as their (coalesced, out-of-order) solves land."""
         lines = [ln for ln in body.decode("utf-8", "replace").splitlines()
                  if ln.strip()]
         metrics.counter("serve.http.posts").inc()
-        tasks = [asyncio.ensure_future(self._one(ln, verb))
-                 for ln in lines]
+        t0 = time.monotonic()
+        ahead = _PARSE_AHEAD
+        refill = max(ahead // 2, 1)
+        todo = iter(lines)
+        queued: collections.deque = collections.deque()
+
+        def feed(n: int) -> None:
+            chunk = list(itertools.islice(todo, n))
+            if not chunk:
+                return
+            with trace.span("serve.parse", lines=len(chunk)):
+                parsed = [self._parse(ln, verb) for ln in chunk]
+            queued.extend(asyncio.ensure_future(self._one(req, t0))
+                          if isinstance(req, protocol.Request) else req
+                          for req in parsed)
+
+        feed(ahead)
         writer.write(
             b"HTTP/1.1 200 OK\r\n"
             b"Content-Type: application/x-ndjson\r\n"
             b"Transfer-Encoding: chunked\r\n"
             b"Connection: close\r\n\r\n")
-        for t in tasks:
-            row = await t
+        while queued:
+            t = queued.popleft()
+            row = t if isinstance(t, dict) else await t
+            if len(queued) <= ahead - refill:
+                feed(refill)
             data = (json.dumps(row) + "\n").encode()
             writer.write(b"%x\r\n%s\r\n" % (len(data), data))
             await writer.drain()   # transport backpressure, per line
         writer.write(b"0\r\n\r\n")
         await writer.drain()
 
-    async def _one(self, line: str, verb: str | None) -> dict:
+    @staticmethod
+    def _parse(line: str, verb: str | None) -> "protocol.Request | dict":
+        """One request line parsed, or its error response."""
         req_id = None
-        t0 = time.monotonic()
         try:
             try:
                 d = json.loads(line)
@@ -198,7 +259,14 @@ class App:
                 req_id = d.get("id")
                 if verb is not None:
                     d = {**d, "kind": verb}
-            req = protocol.parse_request(d)
+            return protocol.parse_request(d)
+        except Exception as e:   # per-line isolation: stream continues
+            if not isinstance(e, ServeError):
+                metrics.counter("serve.http.errors").inc()
+            return protocol.error_response(req_id, e)
+
+    async def _one(self, req: "protocol.Request", t0: float) -> dict:
+        try:
             result = await self.coalescer.submit(
                 req.scenario, verb=req.verb, deadline_s=req.deadline_s)
             return protocol.build_response(
@@ -206,7 +274,7 @@ class App:
         except Exception as e:   # per-line isolation: stream continues
             if not isinstance(e, ServeError):
                 metrics.counter("serve.http.errors").inc()
-            return protocol.error_response(req_id, e)
+            return protocol.error_response(req.id, e)
 
     async def _json(self, writer, status: int, payload: dict) -> None:
         data = (json.dumps(payload, sort_keys=True) + "\n").encode()

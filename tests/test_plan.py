@@ -460,6 +460,23 @@ def test_placed_batch_plan_swaps():
         plan.run(placement=moved[:2])
 
 
+def test_placed_swap_keeps_the_plans_lanes():
+    # A swap of fewer groups per domain packs onto the plan's K lanes
+    # (neutral trailing lanes), so it runs the solver the plan compiled.
+    batch = _placed_sweep(6)
+    plan = api.compile(batch)
+    K = plan.grid.n.shape[2]
+    from repro.core.topology import Placed
+    single = [[Placed(row[0].group, row[0].domain)]
+              for row in batch.placements]
+    got = plan.run(placement=single, backend="numpy")
+    assert got.grid.bw_group.shape[2] == K > 1
+    for i, row in enumerate(single):
+        ref = api.predict(api.Scenario.on("CLX").using("CLX-2S").placed(
+            "DCOPY", row[0].group.n, row[0].domain), backend="numpy")
+        assert got[i].bw_group == ref.bw_group
+
+
 def test_fused_ensemble_seed_stability():
     # Pinned member results: the fused batch×ensemble path must keep
     # every (scenario, member) row bit-identical to the explicit

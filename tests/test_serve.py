@@ -21,6 +21,7 @@ import pytest
 from repro import api
 from repro.core import backend
 from repro.obs import trace
+from repro.serve import keys
 from repro.serve import (App, BadRequest, Coalescer, DeadlineExceeded,
                          Draining, PlanCache, QueueFull, ServeConfig,
                          build_response, error_response, parse_request,
@@ -424,6 +425,9 @@ class _Server:
     """App on a background thread with its own loop (the client side of
     the test is blocking http.client)."""
 
+    def __init__(self, config=None):
+        self.config = config or ServeConfig(tick_s=1e-3)
+
     def __enter__(self):
         self.loop = asyncio.new_event_loop()
         ready = threading.Event()
@@ -433,7 +437,7 @@ class _Server:
             asyncio.set_event_loop(self.loop)
 
             async def go():
-                self.app = App(ServeConfig(tick_s=1e-3))
+                self.app = App(self.config)
                 self.box["stop"] = asyncio.Event()
                 self.port = await self.app.start(port=0)
                 ready.set()
@@ -486,3 +490,237 @@ def test_http_round_trip_streams_in_order():
         status, err = client.get_json("127.0.0.1", srv.port, "/wat")
         assert status == 404 and not err["ok"]
     # Exiting the context asserts a clean drain/shutdown.
+
+
+# ---------------------------------------------------------------------------
+# placed batch posts: one plan per node, placements swapped per tick
+# ---------------------------------------------------------------------------
+
+NPS4 = [f"ROME/s{s}/d{d}" for s in range(2) for d in range(4)]
+ROME_KERNELS = ["ADD", "DAXPY", "DCOPY", "DDOT2", "DSCAL", "STREAM",
+                "vectorSUM"]
+
+
+def _placed_rows(count, seed):
+    """Request lines drawn as the placements cell draws scenarios: 1-3
+    Table II groups of 1-4 threads, each on a random NPS4 domain within
+    its 8 cores."""
+    rng = np.random.default_rng(seed)
+    rows = []
+    for i in range(count):
+        used = [0] * len(NPS4)
+        groups = []
+        for _ in range(rng.integers(1, 4)):
+            d = int(rng.integers(len(NPS4)))
+            free = 8 - used[d]
+            if not free:
+                continue
+            n = int(rng.integers(1, min(4, free) + 1))
+            used[d] += n
+            groups.append({"kernel": ROME_KERNELS[int(rng.integers(7))],
+                           "n": n, "domain": NPS4[d]})
+        rows.append({"id": i, "arch": "ROME", "topology": "ROME-2S-NPS4",
+                     "groups": groups})
+    return rows
+
+
+def _coalesce(scs, config):
+    async def main():
+        async with Coalescer(config) as c:
+            got = await asyncio.gather(*[c.submit(sc) for sc in scs])
+            return got, c.cache.stats(), c.stats()
+    return asyncio.run(main())
+
+
+def test_placed_batch_posts_over_http():
+    from repro.serve import client
+
+    rows = _placed_rows(2048, 1)
+    posts = [rows[:300], rows[300:1100], rows[1100:]]
+    config = ServeConfig(tick_s=1e-3, max_batch=2048, max_queue=4096)
+    with _Server(config) as srv:
+        out = [r for post in posts
+               for r in client.solve("127.0.0.1", srv.port, post,
+                                     path="/v1/predict")]
+        status, stats = client.get_json("127.0.0.1", srv.port, "/statsz")
+    assert [r["id"] for r in out] == [r["id"] for r in rows]
+    assert all(r["ok"] for r in out)
+    scs = [parse_request(r).scenario for r in rows]
+    batch = api.ScenarioBatch.of(scs)
+    plan = api.compile(batch)
+    assert plan.engine == "jax"
+    ref = plan.run(placement=batch.placements)
+    for i, (sc, r) in enumerate(zip(scs, out)):
+        got = [g["bw"] for g in r["groups"]]
+        assert got == list(ref[i].bw_group)
+        assert [g["provenance"] for g in r["groups"]] == \
+            list(sc.provenance)
+        np.testing.assert_allclose(
+            got, api.predict(sc, backend="numpy").bw_group, rtol=1e-12)
+    # Every placed line of the node went through one plan per tick.
+    assert stats["plan_cache"]["misses"] <= stats["coalescer"]["ticks"]
+
+
+def test_parse_ahead_parses_a_post_in_pieces(monkeypatch):
+    from repro.serve import client, http
+
+    rows = _placed_rows(100, 8)
+    got = {}
+    trace.enable(clear_events=True)
+    try:
+        for ahead in (None, 16):
+            monkeypatch.setattr(http, "_PARSE_AHEAD", ahead or 2048)
+            with _Server(ServeConfig(tick_s=1e-3)) as srv:
+                got[ahead] = client.solve("127.0.0.1", srv.port, rows,
+                                          path="/v1/predict")
+            got[ahead, "parsed"] = [e[6]["lines"] for e in trace.events()
+                                    if e[1] == "serve.parse"]
+            trace.clear()
+    finally:
+        trace.disable()
+    assert [r["id"] for r in got[16]] == list(range(100))
+    assert all(r["ok"] for r in got[16])
+    for whole, piece in zip(got[None], got[16]):
+        np.testing.assert_allclose([g["bw"] for g in piece["groups"]],
+                                   [g["bw"] for g in whole["groups"]],
+                                   rtol=1e-12)
+    # The whole post on arrival, or 16 lines and then pieces of 8.
+    assert got[None, "parsed"] == [100]
+    assert got[16, "parsed"] == [16] + [8] * 10 + [4]
+
+
+def test_placed_lines_share_one_plan_per_bucket():
+    scs = [parse_request(r).scenario for r in _placed_rows(1024, 2)]
+    # By structure these would be hundreds of plans...
+    assert len({api.structure_key(sc) for sc in scs}) > 500
+    got, cache, stats = _coalesce(
+        scs, ServeConfig(tick_s=0.05, max_batch=1024, max_queue=2048))
+    # ...but one tick compiles one plan: its (row, lane) bucket's.
+    assert stats["ticks"] == 1 and stats["completed"] == 1024
+    assert cache["misses"] == cache["entries"] == 1
+    for sc, g in list(zip(scs, got))[::37]:
+        np.testing.assert_allclose(
+            g.bw_group, api.predict(sc, backend="numpy").bw_group,
+            rtol=1e-12)
+
+
+def test_device_tick_span_and_queue_time():
+    from repro.obs import metrics
+    scs = [parse_request(r).scenario for r in _placed_rows(256, 3)]
+
+    def queued():
+        return sum(r["count"] for r in metrics.snapshot()
+                   if r["name"] == "serve.queue_s")
+
+    before = queued()
+    trace.enable(clear_events=True)
+    try:
+        got, _, stats = _coalesce(scs, ServeConfig(tick_s=0.05,
+                                                   max_batch=256))
+    finally:
+        trace.disable()
+    # One tick, one solve on the device path, labelled with its backend;
+    # every request's queue time recorded.
+    solves = [e for e in trace.events() if e[1] == "serve.solve"]
+    assert [(e[6]["backend"], e[6]["n"]) for e in solves] == [("jax", 256)]
+    assert queued() - before == 256 == stats["completed"]
+    trace.clear()
+
+
+def test_placed_requests_key_by_their_node():
+    a, b = (parse_request(r).scenario for r in _placed_rows(2, 6))
+    assert api.structure_key(a) != api.structure_key(b)
+    for sc in (a, b, _scenarios(1)[0]):
+        assert api.structure_key(sc)[:4] == api.node_key(sc)
+    assert keys.group_key(a, "predict") == keys.group_key(b, "predict") \
+        == api.node_key(a, verb="predict")
+
+
+def test_large_tick_answers_in_pieces(monkeypatch):
+    from repro.serve import coalesce
+    monkeypatch.setattr(coalesce, "_PIECE", 4)
+    scs = [parse_request(r).scenario for r in _placed_rows(18, 7)]
+
+    async def main():
+        seen = []
+        async with Coalescer(ServeConfig(tick_s=0.05)) as c:
+            futs = [asyncio.ensure_future(c.submit(sc)) for sc in scs]
+
+            async def watch():
+                while not all(f.done() for f in futs):
+                    seen.append(sum(f.done() for f in futs))
+                    await asyncio.sleep(0)
+
+            await asyncio.gather(watch(), *futs)
+            return [f.result() for f in futs], seen, c.stats()
+
+    got, seen, stats = asyncio.run(main())
+    assert stats["ticks"] == 1
+    # One tick, answered four requests at a time, in request order.
+    assert {4, 8, 12, 16} <= set(seen) and max(seen) < 18
+    for sc, g in zip(scs, got):
+        np.testing.assert_allclose(
+            g.bw_group, api.predict(sc, backend="numpy").bw_group,
+            rtol=1e-12)
+
+
+def test_server_times_its_full_collections(monkeypatch):
+    import gc
+    from repro.serve import http
+    monkeypatch.setattr(http, "_GC_PERIOD_S", 0.01)
+    saved = gc.get_threshold()
+    trace.enable(clear_events=True)
+    try:
+        with _Server(ServeConfig(tick_s=1e-3)):
+            during = gc.get_threshold()
+            threading.Event().wait(0.1)
+    finally:
+        trace.disable()
+    assert during == (saved[0], saved[1], 2**31 - 1)
+    assert gc.get_threshold() == saved
+    assert any(e[1] == "serve.gc" for e in trace.events())
+    trace.clear()
+
+
+def test_bad_placed_request_fails_alone():
+    good = [parse_request(r).scenario for r in _placed_rows(20, 4)]
+    bad = api.Scenario.on("ROME").using("ROME-2S-NPS4") \
+        .placed("DCOPY", 9, NPS4[0])          # 9 threads on 8 cores
+    lost = api.Scenario.on("ROME").using("ROME-2S-NPS4") \
+        .placed("DCOPY", 1, "ROME/s7/d0")
+
+    async def main():
+        async with Coalescer(ServeConfig(tick_s=0.05)) as c:
+            return await asyncio.gather(
+                *[c.submit(sc) for sc in good + [bad, lost]],
+                return_exceptions=True)
+
+    got = asyncio.run(main())
+    for sc, g in zip(good, got):
+        assert g.bw_group == api.predict(sc, backend="numpy").bw_group
+    assert isinstance(got[-2], BadRequest) and "overcommitted" in \
+        str(got[-2])
+    assert isinstance(got[-1], BadRequest) and "unknown domain" in \
+        str(got[-1])
+
+
+def test_placed_warmup_covers_row_and_lane_buckets():
+    probe = api.Scenario.on("ROME").using("ROME-2S-NPS4") \
+        .placed("ADD", 1, NPS4[0])
+    cache = PlanCache()
+    # Rows 8 and 64, lanes 1, 2 and 3 -> 4: six plans.
+    assert cache.warmup(probe, buckets=(5, 64), lanes=(1, 2, 3)) == 6
+    compiles = backend.cache_stats()["xla"]["compiles"]
+    scs = [parse_request(r).scenario for r in _placed_rows(60, 5)]
+
+    async def main():
+        async with Coalescer(ServeConfig(tick_s=0.05), cache=cache) as c:
+            return await asyncio.gather(*[c.submit(sc) for sc in scs])
+
+    got = asyncio.run(main())
+    assert len(got) == 60
+    st = cache.stats()
+    assert st["misses"] == 6 and st["hits"] >= 1
+    # The live tick compiled no solver program either.
+    assert backend.cache_stats()["xla"]["compiles"] == compiles
+    assert cache.warmup(probe, buckets=(5, 64), lanes=(1, 2, 3)) == 0

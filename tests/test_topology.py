@@ -129,6 +129,24 @@ def test_strict_capacity_and_unknown_domain():
         predict_placed(topo, [Placed(big, "CLX/d7")])
 
 
+def test_check_placement_groups_by_domain_in_placement_order():
+    topo = preset("ROME-2S-NPS4")
+    names = topo.domain_names
+    g1, g2, g3 = (Group(n=n, f=0.2, bs=100.0) for n in (3, 4, 1))
+    placed = [Placed(g1, names[5]), Placed(g2, names[0]),
+              Placed(g3, names[5])]
+    assert topology.check_placement(topo, placed) == {
+        5: [(0, g1), (2, g3)], 0: [(1, g2)]}
+    assert topology.check_placement(topo, []) == {}
+    over = placed + [Placed(g2, names[5]), Placed(g3, names[5])]  # 9 > 8
+    with pytest.raises(ValueError, match=f"{names[5]}.*overcommitted: 9"):
+        topology.check_placement(topo, over)
+    assert len(topology.check_placement(topo, over, strict=False)[5]) == 4
+    with pytest.raises(KeyError, match="placement 1 names unknown domain"):
+        topology.check_placement(topo, [placed[0], Placed(g1, "ROME/s9")],
+                                 strict=False)
+
+
 def test_empty_placement_and_idle_domains():
     topo = preset("ROME-2S-NPS4")
     pred = predict_placed(topo, [])
