@@ -48,7 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import random
-from typing import Mapping, Sequence
+from collections.abc import Mapping, Sequence
 
 import numpy as np
 
@@ -432,6 +432,28 @@ class BatchPlan(Plan):
         return dataclasses.replace(self.run(), sensitivities=sens)
 
 
+class _SwappedProvenance(Sequence):
+    """The labels of a placement swap, built when a scenario is read:
+    swapped placements may change per-scenario group counts, so each
+    row keeps the plan's labels where they still line up, "" beyond."""
+
+    __slots__ = ("_rows", "_offsets")
+
+    def __init__(self, rows: Sequence[tuple[str, ...]],
+                 offsets: np.ndarray):
+        self._rows = rows
+        self._offsets = offsets
+
+    def __len__(self) -> int:
+        return len(self._rows)
+
+    def __getitem__(self, i: int) -> tuple[str, ...]:
+        i = range(len(self))[i]
+        row = self._rows[i]
+        groups = int(self._offsets[i + 1] - self._offsets[i])
+        return row[:groups] + ("",) * (groups - len(row))
+
+
 @dataclasses.dataclass(frozen=True, eq=False)
 class PlacedBatchPlan(Plan):
     """B placements on one topology packed once → the grid solver.
@@ -507,16 +529,19 @@ class PlacedBatchPlan(Plan):
     def _run_traced(self, sp, cores, f, b_s, placement, backend,
                     jax_cutoff, chunk) -> PlacedBatchPrediction:
         grid = self.grid
+        prov = self.provenance
         if placement is not None:
-            placement = [tuple(p) for p in placement]
+            placement = tuple(map(tuple, placement))
             if len(placement) != len(self):
                 raise ValueError(
                     f"placement gives {len(placement)} scenarios for the "
                     f"plan's {len(self)}")
-            with trace.span("api.plan.pack"):
+            with trace.span("api.plan.pack") as pack:
                 grid = topology_mod.pack_placed(
                     self.topo, placement, strict=self.strict,
                     lanes=self.grid.n.shape[2])
+                pack.set(groups=int(grid.offsets[-1]), K=grid.n.shape[2])
+            prov = _SwappedProvenance(prov, grid.offsets)
         n_arr = _swap_array(grid.n, cores, "cores")
         f_arr = _swap_array(grid.f, f, "f")
         bs_arr = _swap_array(grid.bs, b_s, "b_s")
@@ -527,14 +552,6 @@ class PlacedBatchPlan(Plan):
             chunk=chunk if chunk is not None else self.chunk,
             **self.solver_options)
         raw = topology_mod.TopologyBatchPrediction(grid=grid, shares=shares)
-        prov = self.provenance
-        if placement is not None:
-            # Swapped placements may change per-scenario group counts;
-            # keep labels where they still line up, "" beyond.
-            prov = tuple(
-                tuple(row[j] if j < len(row) else ""
-                      for j in range(len(pl)))
-                for row, pl in zip(prov, placement))
         return PlacedBatchPrediction(archs=self.archs, engine=resolved,
                                      raw=raw, provenance=prov)
 

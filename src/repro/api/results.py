@@ -381,7 +381,9 @@ class PlacedBatchPrediction:
     archs: tuple[str, ...]   # (B,) per-scenario architecture labels
     engine: str              # solver backend: "numpy" | "jax"
     raw: TopologyBatchPrediction
-    provenance: tuple[tuple[str, ...], ...]  # (B, J) input-order labels
+    #: (B, J) input-order labels; a placement swap builds each row when
+    #: it is read.
+    provenance: Sequence[tuple[str, ...]]
     #: Jacobians attached by ``plan.grad(...)`` — ``(B, D, K, K)`` in
     #: grid coordinates; None on plain solves.
     sensitivities: Sensitivities | None = None

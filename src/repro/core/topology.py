@@ -24,7 +24,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Iterable, Mapping, Sequence
+from collections.abc import Sequence
+from typing import Mapping
 
 import numpy as np
 
@@ -224,21 +225,49 @@ class PlacedGrid:
 
     ``D`` is the topology's full leaf count (depth-first, matching
     :attr:`Topology.domains`); ``K`` is the largest per-domain group count
-    across the whole batch.  ``slots[b][j]`` gives the ``(d, k)`` cell
-    scenario *b*'s *j*-th placement landed in, so results on the grid can
-    be read back in input order.
+    across the whole batch.  The batch's groups, flat in input order,
+    landed in cells ``(slot_d[g], slot_k[g])``; scenario *b*'s are flat
+    rows ``offsets[b]:offsets[b + 1]``.  ``slots[b][j]`` gives the
+    ``(d, k)`` cell of scenario *b*'s *j*-th placement, so results on the
+    grid can be read back in input order.
     """
 
     topology: Topology
     placements: tuple[tuple[Placed, ...], ...]
-    n: np.ndarray     # (B, D, K)
-    f: np.ndarray     # (B, D, K)
-    bs: np.ndarray    # (B, D, K)
-    mask: np.ndarray  # (B, D, K) bool, True = occupied
-    slots: tuple[tuple[tuple[int, int], ...], ...]
+    n: np.ndarray        # (B, D, K)
+    f: np.ndarray        # (B, D, K)
+    bs: np.ndarray       # (B, D, K)
+    mask: np.ndarray     # (B, D, K) bool, True = occupied
+    offsets: np.ndarray  # (B + 1,) int64
+    slot_d: np.ndarray   # (G,) int64
+    slot_k: np.ndarray   # (G,) int64
 
     def __len__(self) -> int:
         return len(self.placements)
+
+    @property
+    def slots(self) -> "GridSlots":
+        return GridSlots(self)
+
+
+class GridSlots(Sequence):
+    """``slots[b]``: scenario *b*'s ``(d, k)`` cells in input order, as a
+    tuple built when it is read."""
+
+    __slots__ = ("_grid",)
+
+    def __init__(self, grid: PlacedGrid):
+        self._grid = grid
+
+    def __len__(self) -> int:
+        return len(self._grid)
+
+    def __getitem__(self, b: int) -> tuple[tuple[int, int], ...]:
+        grid = self._grid
+        b = range(len(grid))[b]
+        lo, hi = grid.offsets[b], grid.offsets[b + 1]
+        return tuple(zip(grid.slot_d[lo:hi].tolist(),
+                         grid.slot_k[lo:hi].tolist()))
 
 
 def check_placement(topology: Topology, placements: Sequence[Placed], *,
@@ -282,43 +311,63 @@ def pack_placed(topology: Topology,
 
     Groups keep their placement order within each domain (the same order
     :func:`predict_placed` packs them in, so grid solves are bit-for-bit
-    comparable).  Each scenario is checked by :func:`check_placement`
-    (overcommits only under ``strict``).  The grid
-    has at least ``lanes`` lanes per domain (trailing empty lanes are
-    neutral), so batches of fewer groups can keep a solver's shape.
+    comparable).  One pass over the batch's groups reads their domains
+    and numbers into flat arrays; lanes, checks and the grid are array
+    work from there.  A scenario that names an unknown domain, or (under
+    ``strict``) overcommits one, fails as :func:`check_placement` has it,
+    the lowest such scenario first.  The grid has at least ``lanes``
+    lanes per domain (trailing empty lanes are neutral), so batches of
+    fewer groups can keep a solver's shape.
     """
-    placements_batch = tuple(tuple(p) for p in placements_batch)
+    placements_batch = tuple(map(tuple, placements_batch))
     B, D = len(placements_batch), len(topology.domains)
+    offsets = np.zeros(B + 1, np.int64)
+    np.cumsum(np.fromiter(map(len, placements_batch), np.int64, B),
+              out=offsets[1:])
+    domain_of = topology.domain_index.get
+    flat: list = []
+    put = flat.extend
+    for placements in placements_batch:
+        for p in placements:
+            g = p.group
+            put((domain_of(p.domain, -1), g.n, g.f, g.bs))
+    cols = np.array(flat, np.float64).reshape(-1, 4)
+    d = cols[:, 0].astype(np.int64)
+    n_g, f_g, bs_g = cols[:, 1], cols[:, 2], cols[:, 3]
+    scenario = np.repeat(np.arange(B), np.diff(offsets))
+    cell = scenario * D + d            # (b, d) of each group, flat
 
-    per_scenario: list[dict[int, list[tuple[int, Group]]]] = []
-    K = max(int(lanes), 1)
-    for b, placements in enumerate(placements_batch):
+    known = d >= 0
+    bad = np.zeros(B, bool)
+    bad[scenario[~known]] = True
+    if strict:
+        used = np.bincount(cell[known], weights=n_g[known],
+                           minlength=B * D).reshape(B, D)
+        bad |= (used > [dom.n_cores for dom in topology.domains]).any(1)
+    for b in np.flatnonzero(bad).tolist():
         try:
-            per_domain = check_placement(topology, placements,
-                                         strict=strict)
+            check_placement(topology, placements_batch[b], strict=strict)
         except (KeyError, ValueError) as e:
             raise type(e)(f"scenario {b}: {e.args[0]}") from None
-        per_scenario.append(per_domain)
-        K = max(K, *(len(v) for v in per_domain.values()), 1)
 
-    n = np.zeros((B, D, K))
-    f = np.zeros((B, D, K))
-    bs = np.zeros((B, D, K))
-    mask = np.zeros((B, D, K), dtype=bool)
-    slots: list[tuple[tuple[int, int], ...]] = []
-    for b, per_domain in enumerate(per_scenario):
-        slot_of: dict[int, tuple[int, int]] = {}
-        for d, entries in per_domain.items():
-            for k, (idx, g) in enumerate(entries):
-                n[b, d, k] = g.n
-                f[b, d, k] = g.f
-                bs[b, d, k] = g.bs
-                mask[b, d, k] = True
-                slot_of[idx] = (d, k)
-        slots.append(tuple(slot_of[j]
-                           for j in range(len(placements_batch[b]))))
+    # A group's lane is its rank among its (b, d) cell's groups, in
+    # placement order: a stable sort lines each cell's groups up.
+    order = np.argsort(cell, kind="stable")
+    runs = np.flatnonzero(np.diff(cell[order], prepend=-1))
+    k = np.empty_like(cell)
+    k[order] = np.arange(len(cell)) - np.repeat(
+        runs, np.diff(runs, append=len(cell)))
+    K = max(int(lanes), int(k.max(initial=-1)) + 1, 1)
+
+    at = cell * K + k
+    n, f, bs = (np.zeros(B * D * K) for _ in range(3))
+    mask = np.zeros(B * D * K, bool)
+    n[at], f[at], bs[at], mask[at] = n_g, f_g, bs_g, True
+    shape = (B, D, K)
     return PlacedGrid(topology=topology, placements=placements_batch,
-                      n=n, f=f, bs=bs, mask=mask, slots=tuple(slots))
+                      n=n.reshape(shape), f=f.reshape(shape),
+                      bs=bs.reshape(shape), mask=mask.reshape(shape),
+                      offsets=offsets, slot_d=d, slot_k=k)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -349,17 +398,19 @@ class TopologyBatchPrediction:
     @property
     def bw_group(self) -> tuple[tuple[float, ...], ...]:
         """Per scenario, attained bandwidths in input placement order."""
-        return tuple(
-            tuple(float(self.shares.bw_group[b, d, k])
-                  for d, k in self.grid.slots[b])
-            for b in range(len(self)))
+        grid = self.grid
+        scenario = np.repeat(np.arange(len(grid)), np.diff(grid.offsets))
+        flat = self.shares.bw_group[scenario, grid.slot_d,
+                                    grid.slot_k].tolist()
+        bounds = grid.offsets.tolist()
+        return tuple(tuple(flat[lo:hi])
+                     for lo, hi in zip(bounds, bounds[1:]))
 
-    def _group_at(self, i: int, j: int) -> Group:
-        """Input placement j's group, with numbers read back from the
-        solved grid — so ``plan.run(f=..., cores=...)`` number swaps
-        show up in materialized results, not just in the arrays."""
-        d, k = self.grid.slots[i][j]
-        g = self.grid.placements[i][j].group
+    def _group_at(self, i: int, d: int, k: int, g: Group) -> Group:
+        """The group placed in cell ``(d, k)`` of scenario i, with
+        numbers read back from the solved grid — so ``plan.run(f=...,
+        cores=...)`` number swaps show up in materialized results, not
+        just in the arrays."""
         n_, f_, bs_ = (float(self.shares.n[i, d, k]),
                        float(self.shares.f[i, d, k]),
                        float(self.shares.bs[i, d, k]))
@@ -369,32 +420,34 @@ class TopologyBatchPrediction:
 
     def scenario(self, i: int) -> TopologyPrediction:
         """The i-th solution, shaped exactly like :func:`predict_placed`."""
+        slots = self.grid.slots[i]
         placements = tuple(
-            dataclasses.replace(p, group=self._group_at(i, j))
-            for j, p in enumerate(self.grid.placements[i]))
-        names = self.topology.domain_names
+            dataclasses.replace(p, group=self._group_at(i, d, k, p.group))
+            for p, (d, k) in zip(self.grid.placements[i], slots))
+        # Each domain's (lane, placement index) pairs: lanes were handed
+        # out in placement order, so they come out in lane order.
+        lanes: dict[int, list[tuple[int, int]]] = {}
+        for j, (d, k) in enumerate(slots):
+            lanes.setdefault(d, []).append((k, j))
         by_domain: dict[str, SharePrediction] = {}
-        slot_to_idx = {s: j for j, s in enumerate(self.grid.slots[i])}
-        for d, name in enumerate(names):
-            ks = [k for k in range(self.grid.mask.shape[2])
-                  if self.grid.mask[i, d, k]]
-            if not ks:
+        for d, name in enumerate(self.topology.domain_names):
+            entries = lanes.get(d)
+            if not entries:
                 by_domain[name] = SharePrediction(
                     groups=(), b_overlap=0.0, alphas=(), bw_group=())
                 continue
             by_domain[name] = SharePrediction(
-                groups=tuple(placements[slot_to_idx[(d, k)]].group
-                             for k in ks),
+                groups=tuple(placements[j].group for _, j in entries),
                 b_overlap=float(self.shares.b_overlap[i, d]),
                 alphas=tuple(float(self.shares.alphas[i, d, k])
-                             for k in ks),
+                             for k, _ in entries),
                 bw_group=tuple(float(self.shares.bw_group[i, d, k])
-                               for k in ks))
+                               for k, _ in entries))
         return TopologyPrediction(
             topology=self.topology, placements=placements,
             by_domain=by_domain,
             bw_group=tuple(float(self.shares.bw_group[i, d, k])
-                           for d, k in self.grid.slots[i]))
+                           for d, k in slots))
 
 
 def predict_placed_batch(topology: Topology,

@@ -33,7 +33,9 @@ from hypothesis import strategies as st
 from repro import api
 from repro.core import backend
 from repro.core.sharing import Group, solve_batch, solve_placed_batch
-from repro.core.topology import (Placed, pack_placed, predict_placed,
+from repro.api.results import from_topology_prediction
+from repro.core.topology import (PRESETS, Placed, check_placement,
+                                 pack_placed, predict_placed,
                                  predict_placed_batch, preset)
 
 TOPOLOGIES = ["CLX", "CLX-2S", "ROME-2S-NPS4", "TPUv5e-pod4"]
@@ -288,3 +290,247 @@ def test_pack_placed_validation_messages():
     grid = pack_placed(topo, [[Placed(Group(cap + 1, 0.5, 100.0),
                                       "CLX/d0")]], strict=False)
     assert grid.n[0, 0, 0] == cap + 1
+
+
+# ---------------------------------------------------------------------------
+# Flat packing == the per-scenario packer, bit for bit
+# ---------------------------------------------------------------------------
+
+
+def _reference_pack(topology, placements_batch, *, strict=True, lanes=1):
+    """The per-scenario packer the flat pass replaced: each scenario
+    checked and grouped by :func:`check_placement`, the grid written one
+    cell at a time."""
+    placements_batch = tuple(tuple(p) for p in placements_batch)
+    B, D = len(placements_batch), len(topology.domains)
+    per_scenario = []
+    K = max(int(lanes), 1)
+    for b, placements in enumerate(placements_batch):
+        try:
+            per_domain = check_placement(topology, placements,
+                                         strict=strict)
+        except (KeyError, ValueError) as e:
+            raise type(e)(f"scenario {b}: {e.args[0]}") from None
+        per_scenario.append(per_domain)
+        K = max(K, *(len(v) for v in per_domain.values()), 1)
+    n = np.zeros((B, D, K))
+    f = np.zeros((B, D, K))
+    bs = np.zeros((B, D, K))
+    mask = np.zeros((B, D, K), dtype=bool)
+    slots = []
+    for b, per_domain in enumerate(per_scenario):
+        slot_of = {}
+        for d, entries in per_domain.items():
+            for k, (idx, g) in enumerate(entries):
+                n[b, d, k] = g.n
+                f[b, d, k] = g.f
+                bs[b, d, k] = g.bs
+                mask[b, d, k] = True
+                slot_of[idx] = (d, k)
+        slots.append(tuple(slot_of[j]
+                           for j in range(len(placements_batch[b]))))
+    return n, f, bs, mask, tuple(slots)
+
+
+def _within_capacity(rng, topo, *, max_groups=5, max_n=4):
+    """A random placement list that overcommits no domain."""
+    free = {d.name: d.n_cores for d in topo.domains}
+    out = []
+    for j in range(rng.randint(0, max_groups)):
+        name = rng.choice(topo.domain_names)
+        n = rng.randint(0, min(max_n, free[name]))
+        free[name] -= n
+        out.append(Placed(Group(n, rng.uniform(0.05, 1.0),
+                                rng.uniform(20.0, 220.0), f"g{j}"), name))
+    return out
+
+
+def _sweep_draw(rng, topo, B, *, max_groups=3, max_threads=4):
+    """B placement lists drawn as the placed-sweep benchmark draws them:
+    1 to ``max_groups`` groups of Table II kernels, each on a random
+    domain within its free cores."""
+    from repro.core.table2 import TABLE2
+    kernels = sorted(TABLE2)
+    out = []
+    for _ in range(B):
+        threads = {d.name: 0 for d in topo.domains}
+        row = []
+        for _ in range(rng.randint(1, max_groups)):
+            dom = rng.choice(topo.domains)
+            free = dom.n_cores - threads[dom.name]
+            if not free:
+                continue
+            n = rng.randint(1, min(max_threads, free))
+            threads[dom.name] += n
+            row.append(Placed(Group.of(TABLE2[rng.choice(kernels)],
+                                       "ROME", n), dom.name))
+        out.append(row)
+    return out
+
+
+def _case(name):
+    """``(topology, batch, strict, lanes)`` of one packing case."""
+    rng = random.Random(name)
+    if name.startswith("preset:"):
+        topo = preset(name.split(":", 1)[1])
+        return topo, [_random_placements(rng, topo, max_n=12)
+                      for _ in range(40)], False, 1
+    if name.startswith("strict:"):
+        topo = preset(name.split(":", 1)[1])
+        return topo, [_within_capacity(rng, topo)
+                      for _ in range(40)], True, 1
+    topo = preset("ROME-2S-NPS4")
+    if name == "overcommit-not-strict":
+        cap = topo.domains[0].n_cores
+        batch = [_within_capacity(rng, topo) for _ in range(12)]
+        batch[5] = [Placed(Group(cap, 0.5, 100.0), topo.domain_names[0]),
+                    Placed(Group(cap, 0.4, 90.0), topo.domain_names[0])]
+        return topo, batch, False, 1
+    if name == "lanes-above-K":
+        return topo, [_within_capacity(rng, topo, max_groups=2)
+                      for _ in range(12)], True, 6
+    if name == "empty-scenarios":
+        batch = [_within_capacity(rng, topo) for _ in range(12)]
+        for b in (0, 4, 11):
+            batch[b] = []
+        return topo, batch, True, 2
+    if name == "all-empty":
+        return topo, [[] for _ in range(5)], True, 1
+    if name == "no-scenarios":
+        return topo, [], True, 3
+    if name == "idle-domains":
+        # Every group on one domain: seven of eight stay idle.
+        dom = topo.domain_names[5]
+        return topo, [[Placed(Group(1, 0.1 * (j + 1), 50.0 + j), dom)
+                       for j in range(b % 4)] for b in range(9)], True, 1
+    if name == "placed-sweep-2^12":
+        return topo, _sweep_draw(rng, topo, 1 << 12), True, 1
+    raise KeyError(name)
+
+
+PACK_CASES = ([f"preset:{t}" for t in sorted(PRESETS)]
+              + [f"strict:{t}" for t in TOPOLOGIES]
+              + ["overcommit-not-strict", "lanes-above-K",
+                 "empty-scenarios", "all-empty", "no-scenarios",
+                 "idle-domains", "placed-sweep-2^12"])
+
+
+@pytest.mark.parametrize("name", PACK_CASES)
+def test_flat_pack_is_the_per_scenario_pack_bit_for_bit(name):
+    topo, batch, strict, lanes = _case(name)
+    grid = pack_placed(topo, batch, strict=strict, lanes=lanes)
+    *arrays, slots = _reference_pack(topo, batch, strict=strict,
+                                     lanes=lanes)
+    for want, got in zip(arrays, (grid.n, grid.f, grid.bs, grid.mask)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+    assert len(grid.slots) == len(slots) == len(batch)
+    for b, want in enumerate(slots):
+        assert grid.slots[b] == want
+        assert grid.slots[b - len(batch)] == want
+    with pytest.raises(IndexError):
+        grid.slots[len(batch)]
+
+
+def _bad(topo, kind):
+    """One scenario's placements that fail ``kind``: an unknown domain,
+    an overcommitted domain, or both (the overcommit listed first)."""
+    cap = topo.domains[0].n_cores
+    over = [Placed(Group(cap, 0.5, 100.0), topo.domain_names[0]),
+            Placed(Group(1, 0.5, 100.0), topo.domain_names[0])]
+    unknown = [Placed(Group(1, 0.5, 100.0), "nowhere")]
+    return {"unknown": unknown, "over": over,
+            "over-then-unknown": over + unknown}[kind]
+
+
+@pytest.mark.parametrize("faults,strict,want", [
+    ({3: "unknown"}, True, (3, KeyError)),
+    ({2: "over"}, True, (2, ValueError)),
+    ({2: "over"}, False, None),
+    ({3: "unknown", 5: "over"}, True, (3, KeyError)),
+    ({2: "over", 4: "unknown"}, True, (2, ValueError)),
+    ({2: "over", 4: "unknown"}, False, (4, KeyError)),
+    ({1: "over-then-unknown"}, True, (1, KeyError)),
+    ({6: "over-then-unknown", 7: "over"}, False, (6, KeyError)),
+    ({0: "over", 1: "unknown"}, True, (0, ValueError)),
+])
+def test_flat_pack_raises_as_check_placement(faults, strict, want):
+    rng = random.Random(11)
+    topo = preset("ROME-2S-NPS4")
+    batch = [_within_capacity(rng, topo) for _ in range(8)]
+    for b, kind in faults.items():
+        batch[b] = _bad(topo, kind)
+    if want is None:
+        grid = pack_placed(topo, batch, strict=strict)
+        assert grid.mask.sum() == sum(map(len, batch))
+        return
+    b, kind = want
+    with pytest.raises(kind) as alone:
+        check_placement(topo, batch[b], strict=strict)
+    message = f"scenario {b}: {alone.value.args[0]}"
+    for pack in (pack_placed, _reference_pack):
+        with pytest.raises(kind) as got:
+            pack(topo, batch, strict=strict)
+        assert type(got.value) is kind
+        assert got.value.args == (message,)
+
+
+def _placed_plan_and_swap(swap_groups):
+    """A compiled placed batch of 6 scenarios of 1–2 groups each, and a
+    swap of ``swap_groups`` groups per scenario (some scenarios empty,
+    some with more groups than the plan's rows)."""
+    domains = preset("CLX-2S").domain_names
+    kernels = ["DCOPY", "DDOT2", "DAXPY", "Schoenauer"]
+    scens = []
+    for i in range(6):
+        sc = (api.Scenario.on("CLX").using("CLX-2S")
+              .placed(kernels[i % 4], 1 + i % 3, domains[i % 2]))
+        if i % 2:
+            sc = sc.placed("STREAM", 2, domains[0])
+        scens.append(sc)
+    plan = api.compile(api.ScenarioBatch.of(scens))
+    rng = random.Random(str(swap_groups))
+    from repro.core.table2 import TABLE2
+    swap = [[Placed(Group.of(TABLE2[rng.choice(kernels)], "CLX",
+                             rng.randint(1, 4)), domains[j % 2])
+             for j in range(swap_groups[i % len(swap_groups)])]
+            for i in range(6)]
+    return plan, swap
+
+
+@pytest.mark.parametrize("swap_groups", [(1,), (2,), (0, 3), (4, 1, 0)])
+def test_placement_swap_results_and_labels(swap_groups):
+    plan, swap = _placed_plan_and_swap(swap_groups)
+    pred = plan.run(placement=swap, backend="numpy")
+    assert len(pred.provenance) == len(swap)
+    for i, placements in enumerate(swap):
+        row = plan.provenance[i]
+        labels = tuple(row[j] if j < len(row) else ""
+                       for j in range(len(placements)))
+        assert pred.provenance[i] == labels
+        assert pred.provenance[i - len(swap)] == labels
+        lone = predict_placed(plan.topo, placements, backend="numpy")
+        want = from_topology_prediction(lone, arch="CLX",
+                                        provenance=labels)
+        assert pred[i].to_dict() == want.to_dict()
+        assert pred.bw_group[i] == lone.bw_group
+    with pytest.raises(IndexError):
+        pred.provenance[len(swap)]
+
+
+def test_placement_swap_pack_span_counts_groups_and_lanes():
+    from repro.obs import trace
+    plan, swap = _placed_plan_and_swap((4, 1, 0))
+    was = trace.enabled()
+    trace.enable(clear_events=True)
+    try:
+        pred = plan.run(placement=swap, backend="numpy")
+        packs = [ev for ev in trace.events()
+                 if ev[0] == "span" and ev[1] == "api.plan.pack"]
+    finally:
+        if not was:
+            trace.disable()
+    # Four groups over two domains take two lanes; the plan has one.
+    assert plan.grid.n.shape[2] == 1 and pred.raw.grid.n.shape[2] == 2
+    assert len(packs) == 1
+    assert packs[0][6] == {"groups": sum(map(len, swap)), "K": 2}
