@@ -37,7 +37,7 @@ The pre-facade entry points (``sharing.predict``, ``solve_batch``,
 facade dispatches to, and facade results are bit-for-bit theirs.
 """
 
-from .engine import JAX_BATCH_CUTOFF, predict, simulate
+from .engine import predict, simulate
 from .plan import (BatchPlan, PlacedBatchPlan, PlacedPlan, Plan,
                    ScalarPlan, SimulatePlan, compile, derive_member_seed,
                    infer_verb, node_key, structure_key)
@@ -53,7 +53,7 @@ from .scenario import (DEFAULT_WORK_BYTES, Noise, RunSpec, Scenario,
                        ScenarioBatch, StepSpec)
 
 __all__ = [
-    "predict", "simulate", "JAX_BATCH_CUTOFF",
+    "predict", "simulate",
     "compile", "Plan", "ScalarPlan", "PlacedPlan", "BatchPlan",
     "PlacedBatchPlan", "SimulatePlan", "derive_member_seed",
     "infer_verb", "node_key", "structure_key",

@@ -19,8 +19,8 @@ single, unplaced        scalar reference path (``sharing.predict``)
 single, placed          topology solver (``topology.predict_placed``)
 batch, unplaced         batched array solver (``sharing.solve_arrays``) —
                         numpy, or the substrate's cached jitted jax solver
-                        when importable and B is at least the configured
-                        cutoff (``REPRO_JAX_CUTOFF`` / ``jax_cutoff=``)
+                        when importable and B is at least
+                        ``backend.DEFAULT_JAX_CUTOFF`` (64)
 batch, placed on one    placed-grid solver
 topology                (``sharing.solve_placed_batch`` over the packed
                         ``(B, D, K)`` occupancy grid; dispatch sees the
@@ -40,42 +40,30 @@ resolution itself lives in one place for the whole tree:
 
 from __future__ import annotations
 
-from ..core import backend as backend_mod
 from .plan import compile as compile_plan
 from .results import (BatchPrediction, PlacedBatchPrediction, Prediction,
                       SimulationResult)
 from .scenario import Scenario, ScenarioBatch
 
-#: Default ``backend="auto"`` jax cutoff (see
-#: :data:`repro.core.backend.DEFAULT_JAX_CUTOFF`).  Kept here as the
-#: facade-level alias; the effective value honors the
-#: ``REPRO_JAX_CUTOFF`` environment variable and per-call
-#: ``jax_cutoff=`` overrides.
-JAX_BATCH_CUTOFF = backend_mod.DEFAULT_JAX_CUTOFF
-
 
 def predict(scenario: Scenario | ScenarioBatch, *,
-            backend: str | None = None,
-            jax_cutoff: int | None = None
+            backend: str | None = None
             ) -> Prediction | BatchPrediction | PlacedBatchPrediction:
     """Solve the sharing model (Eqs. 4–5) for a scenario or batch.
 
     One-shot sugar for ``compile(scenario, verb="predict").run(...)``.
     ``backend`` overrides the scenario's own backend option
-    (``"numpy"`` / ``"jax"`` / ``"auto"``); ``jax_cutoff`` overrides
-    the ``auto`` threshold for this call.  Returns a
+    (``"numpy"`` / ``"jax"`` / ``"auto"``).  Returns a
     :class:`Prediction` for a single scenario, a
     :class:`BatchPrediction` for an unplaced batch, a
     :class:`PlacedBatchPrediction` for a batch placed on one topology.
     """
-    return compile_plan(scenario, verb="predict").run(
-        backend=backend, jax_cutoff=jax_cutoff)
+    return compile_plan(scenario, verb="predict").run(backend=backend)
 
 
 def simulate(scenario: Scenario | ScenarioBatch, *,
              backend: str | None = None, t_max: float | None = None,
-             on_deadlock: str = "mask",
-             fuse_ensembles: bool = True) -> SimulationResult:
+             on_deadlock: str = "mask") -> SimulationResult:
     """Run a scenario (or batch) through the desync event engine.
 
     One-shot sugar for ``compile(scenario, verb="simulate").run(...)``.
@@ -85,13 +73,11 @@ def simulate(scenario: Scenario | ScenarioBatch, *,
     a :class:`ScenarioBatch` simulates its B scenarios, each scenario's
     own ensemble fused in as adjacent rows (``result.members`` maps rows
     back to ``(scenario, member)``).  All members advance in **one**
-    batched engine call.  ``fuse_ensembles=False`` forces the legacy
-    one-row-per-scenario contract, which rejects inner ensembles.
+    batched engine call.
 
     ``backend`` (``"numpy"`` default / ``"jax"``) and ``t_max`` override
     the scenarios' options; ``on_deadlock`` is the batched engine's
     masking contract (``"mask"`` or ``"raise"``).
     """
-    return compile_plan(scenario, verb="simulate",
-                        fuse_ensembles=fuse_ensembles).run(
+    return compile_plan(scenario, verb="simulate").run(
         backend=backend, t_max=t_max, on_deadlock=on_deadlock)

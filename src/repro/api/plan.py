@@ -250,8 +250,8 @@ class ScalarPlan(Plan):
     def engine(self) -> str:
         return "scalar"
 
-    def run(self, *, cores=None, f=None, b_s=None, backend=None,
-            jax_cutoff=None, chunk=None) -> Prediction:
+    def run(self, *, cores=None, f=None, b_s=None,
+            backend=None) -> Prediction:
         """Re-solve; ``cores``/``f``/``b_s`` swap per-group numbers
         (scalar or length-G sequence).  ``backend`` is accepted for
         signature uniformity — the scalar path *is* the reference
@@ -298,8 +298,8 @@ class PlacedPlan(Plan):
     def engine(self) -> str:
         return "topology"
 
-    def run(self, *, cores=None, f=None, b_s=None, backend=None,
-            jax_cutoff=None, chunk=None) -> Prediction:
+    def run(self, *, cores=None, f=None, b_s=None,
+            backend=None) -> Prediction:
         with trace.span("api.plan.run", kind=self.kind, engine="topology"):
             placements = self.placements
             if cores is not None or f is not None or b_s is not None:
@@ -311,10 +311,6 @@ class PlacedPlan(Plan):
             kwargs = dict(self.solver_kwargs)
             if backend is not None:
                 kwargs["backend"] = backend
-            if jax_cutoff is not None:
-                kwargs["jax_cutoff"] = jax_cutoff
-            if chunk is not None:
-                kwargs["chunk"] = chunk
             pred = topology_mod.predict_placed(self.topo, placements,
                                                **kwargs)
             return from_topology_prediction(pred, arch=self.arch,
@@ -373,8 +369,6 @@ class BatchPlan(Plan):
     solver_options: dict
     backend: str               # resolved at compile time
     requested_backend: str     # what the scenarios asked for
-    jax_cutoff: int | None
-    chunk: int | None
 
     def __len__(self) -> int:
         return self.n.shape[0]
@@ -388,28 +382,22 @@ class BatchPlan(Plan):
         """The padded jit-cache shape bucket this plan solves in."""
         return (backend_mod.bucket(len(self)), self.n.shape[1])
 
-    def run(self, *, cores=None, f=None, b_s=None, backend=None,
-            jax_cutoff=None, chunk=None) -> BatchPrediction:
+    def run(self, *, cores=None, f=None, b_s=None,
+            backend=None) -> BatchPrediction:
         """Re-solve the batch.  ``cores``/``f``/``b_s`` swap the packed
-        arrays (anything broadcastable to ``(B, G)``); ``backend`` /
-        ``jax_cutoff`` / ``chunk`` re-resolve dispatch for this run
-        only.  Equal to a fresh ``compile(...).run()`` of the modified
-        scenarios, bit for bit."""
+        arrays (anything broadcastable to ``(B, G)``); ``backend``
+        re-resolves dispatch for this run only.  Equal to a fresh
+        ``compile(...).run()`` of the modified scenarios, bit for bit."""
         with trace.span("api.plan.run", kind=self.kind, B=len(self)) as sp:
             n_arr = _swap_array(self.n, cores, "cores")
             f_arr = _swap_array(self.f, f, "f")
             bs_arr = _swap_array(self.bs, b_s, "b_s")
-            if backend is None and jax_cutoff is None:
-                resolved = self.backend
-            else:
-                resolved = backend_mod.resolve(
-                    backend or self.requested_backend, len(self),
-                    jax_cutoff=jax_cutoff if jax_cutoff is not None
-                    else self.jax_cutoff)
+            resolved = self.backend if backend is None else \
+                backend_mod.resolve(backend or self.requested_backend,
+                                    len(self))
             sp.set(engine=resolved)
             b, alphas, util, bw = sharing.solve_arrays(
                 n_arr, f_arr, bs_arr, backend=resolved,
-                chunk=chunk if chunk is not None else self.chunk,
                 **self.solver_options)
             raw = sharing.BatchSharePrediction(
                 n=n_arr, f=f_arr, bs=bs_arr, b_overlap=b, alphas=alphas,
@@ -474,8 +462,6 @@ class PlacedBatchPlan(Plan):
     backend: str               # resolved at compile time
     requested_backend: str
     strict: bool
-    jax_cutoff: int | None
-    chunk: int | None
 
     def __len__(self) -> int:
         return len(self.grid)
@@ -496,18 +482,8 @@ class PlacedBatchPlan(Plan):
         B, D, K = self.grid.n.shape
         return (backend_mod.bucket(B * D), K)
 
-    def _dispatch(self, backend, jax_cutoff) -> str:
-        if backend is None and jax_cutoff is None:
-            return self.backend
-        B, D, _ = self.grid.n.shape
-        return backend_mod.resolve(
-            backend or self.requested_backend, B * D,
-            jax_cutoff=jax_cutoff if jax_cutoff is not None
-            else self.jax_cutoff)
-
     def run(self, *, cores=None, f=None, b_s=None, placement=None,
-            backend=None, jax_cutoff=None, chunk=None
-            ) -> PlacedBatchPrediction:
+            backend=None) -> PlacedBatchPrediction:
         """Re-solve the placed batch.
 
         ``cores``/``f``/``b_s`` swap grid numbers (anything
@@ -518,16 +494,14 @@ class PlacedBatchPlan(Plan):
         plan's topology, re-packed without re-tracing the scenarios onto
         at least the plan's K lanes per domain, so a batch of fewer
         groups per domain runs the solver the plan compiled for.
-        ``backend``/``jax_cutoff``/``chunk`` re-resolve dispatch for
-        this run only.
+        ``backend`` re-resolves dispatch for this run only.
         """
         with trace.span("api.plan.run", kind=self.kind,
                         B=len(self)) as sp:
-            return self._run_traced(sp, cores, f, b_s, placement, backend,
-                                    jax_cutoff, chunk)
+            return self._run_traced(sp, cores, f, b_s, placement, backend)
 
-    def _run_traced(self, sp, cores, f, b_s, placement, backend,
-                    jax_cutoff, chunk) -> PlacedBatchPrediction:
+    def _run_traced(self, sp, cores, f, b_s, placement,
+                    backend) -> PlacedBatchPrediction:
         grid = self.grid
         prov = self.provenance
         if placement is not None:
@@ -545,11 +519,12 @@ class PlacedBatchPlan(Plan):
         n_arr = _swap_array(grid.n, cores, "cores")
         f_arr = _swap_array(grid.f, f, "f")
         bs_arr = _swap_array(grid.bs, b_s, "b_s")
-        resolved = self._dispatch(backend, jax_cutoff)
+        resolved = self.backend if backend is None else \
+            backend_mod.resolve(backend or self.requested_backend,
+                                grid.n.shape[0] * grid.n.shape[1])
         sp.set(engine=resolved)
         shares = sharing.solve_placed_batch(
             n_arr, f_arr, bs_arr, mask=grid.mask, backend=resolved,
-            chunk=chunk if chunk is not None else self.chunk,
             **self.solver_options)
         raw = topology_mod.TopologyBatchPrediction(grid=grid, shares=shares)
         return PlacedBatchPrediction(archs=self.archs, engine=resolved,
@@ -697,7 +672,7 @@ def _topology_fingerprint(topo) -> tuple | None:
 
 def _options_signature(sc: "Scenario") -> tuple:
     return (tuple(sorted(sc.solver_options().items())), sc.backend,
-            sc.jax_cutoff, sc.chunk, sc.strict)
+            sc.strict)
 
 
 def node_key(scenario: "Scenario", *, verb: str | None = None) -> tuple:
@@ -786,25 +761,20 @@ def _compile_predict(scenario) -> Plan:
                 grid = topology_mod.pack_placed(
                     first.topo, scenario.placements, strict=first.strict)
             B, D, _ = grid.n.shape
-            resolved = backend_mod.resolve(first.backend, B * D,
-                                           jax_cutoff=first.jax_cutoff)
             return PlacedBatchPlan(
                 archs=scenario.archs, grid=grid,
                 provenance=scenario.provenance,
                 solver_options=first.solver_options(),
-                backend=resolved, requested_backend=first.backend,
-                strict=first.strict, jax_cutoff=first.jax_cutoff,
-                chunk=first.chunk)
+                backend=backend_mod.resolve(first.backend, B * D),
+                requested_backend=first.backend, strict=first.strict)
         with trace.span("api.compile.pack", B=len(scenario)):
             n, f, bs, names = scenario.arrays
-        resolved = backend_mod.resolve(first.backend, len(scenario),
-                                       jax_cutoff=first.jax_cutoff)
         return BatchPlan(archs=scenario.archs, n=n, f=f, bs=bs,
                          names=names, provenance=scenario.provenance,
                          solver_options=first.solver_options(),
-                         backend=resolved,
-                         requested_backend=first.backend,
-                         jax_cutoff=first.jax_cutoff, chunk=first.chunk)
+                         backend=backend_mod.resolve(first.backend,
+                                                     len(scenario)),
+                         requested_backend=first.backend)
     if not isinstance(scenario, Scenario):
         raise TypeError(
             f"predict() takes a Scenario or ScenarioBatch, got "
@@ -831,8 +801,6 @@ def _compile_predict(scenario) -> Plan:
         kwargs = scenario.solver_options()
         kwargs["backend"] = scenario.backend
         kwargs["strict"] = scenario.strict
-        kwargs["jax_cutoff"] = scenario.jax_cutoff
-        kwargs["chunk"] = scenario.chunk
         return PlacedPlan(arch=scenario.arch, topo=scenario.topo,
                           placements=placements,
                           provenance=scenario.provenance,
@@ -842,42 +810,23 @@ def _compile_predict(scenario) -> Plan:
                       solver_options=scenario.solver_options())
 
 
-def _compile_simulate(scenario, *,
-                      fuse_ensembles: bool = True) -> SimulatePlan:
-    member_map: tuple[tuple[int, int], ...] | None = None
+def _compile_simulate(scenario) -> SimulatePlan:
     if isinstance(scenario, Scenario):
-        members = [(scenario, b)
-                   for b in range(scenario.noise.ensemble
-                                  if scenario.noise else 1)]
         scenarios = [scenario]
     elif isinstance(scenario, ScenarioBatch):
         scenarios = list(scenario.scenarios)
-        if fuse_ensembles:
-            # Batch × ensemble composition: scenario i's E_i noise
-            # members flatten to adjacent rows of one (Σ E_i) run, each
-            # member on its own SplitMix64-derived seed stream.
-            members = [(sc, m) for sc in scenarios
-                       for m in range(sc.noise.ensemble if sc.noise
-                                      else 1)]
-            if len(members) != len(scenarios):
-                member_map = tuple(
-                    (i, m) for i, sc in enumerate(scenarios)
-                    for m in range(sc.noise.ensemble if sc.noise else 1))
-        else:
-            for i, sc in enumerate(scenarios):
-                if sc.noise is not None and sc.noise.ensemble != 1:
-                    raise ValueError(
-                        f"scenario {i} asks for a noise ensemble inside "
-                        f"a ScenarioBatch but fuse_ensembles=False "
-                        f"forces the legacy one-row-per-scenario path; "
-                        f"drop fuse_ensembles=False to run the whole "
-                        f"batch × ensemble grid in one call, or set "
-                        f"ensemble=1 on the scenario")
-            members = [(sc, 0) for sc in scenarios]
     else:
         raise TypeError(
             f"simulate() takes a Scenario or ScenarioBatch, got "
             f"{type(scenario).__name__}")
+    # Batch × ensemble composition: scenario i's E_i noise members
+    # flatten to adjacent rows of one (Σ E_i) run, each member on its
+    # own SplitMix64-derived seed stream.
+    rows = tuple((i, m) for i, sc in enumerate(scenarios)
+                 for m in range(sc.noise.ensemble if sc.noise else 1))
+    members = [(scenarios[i], m) for i, m in rows]
+    member_map = rows if isinstance(scenario, ScenarioBatch) and \
+        len(rows) != len(scenarios) else None
 
     first = scenarios[0]
     t_max_conflict = None
@@ -928,8 +877,7 @@ def _compile_simulate(scenario, *,
 
 
 def compile(scenario: Scenario | ScenarioBatch, *,
-            verb: str | None = None,
-            fuse_ensembles: bool = True) -> Plan:
+            verb: str | None = None) -> Plan:
     """Trace a scenario (or batch) into a frozen, re-runnable plan.
 
     ``verb`` picks the engine family — ``"predict"`` (the Eq. 4–5
@@ -940,12 +888,10 @@ def compile(scenario: Scenario | ScenarioBatch, *,
     ``verb="simulate"`` to run groups through the event engine, exactly
     like calling :func:`repro.api.simulate` on them).
 
-    ``fuse_ensembles`` (simulate only, default on) expands each batch
-    scenario's ``with_noise(ensemble=E)`` members into the compiled
-    run — B scenarios × E seeds as one ``(Σ E_i)``-row engine call,
-    with the row origin recorded on ``plan.members`` /
-    ``result.members``.  ``fuse_ensembles=False`` forces the legacy
-    one-row-per-scenario contract, which rejects inner ensembles.
+    A simulated batch expands each scenario's
+    ``with_noise(ensemble=E)`` members into the compiled run — B
+    scenarios × E seeds as one ``(Σ E_i)``-row engine call, with the row
+    origin recorded on ``plan.members`` / ``result.members``.
 
     All build-time work happens here — registry resolution already
     happened when the scenario was built; this adds validation, array
@@ -961,7 +907,6 @@ def compile(scenario: Scenario | ScenarioBatch, *,
         if verb == "predict":
             plan = _compile_predict(scenario)
         else:
-            plan = _compile_simulate(scenario,
-                                     fuse_ensembles=fuse_ensembles)
+            plan = _compile_simulate(scenario)
         sp.set(kind=plan.kind, engine=plan.engine)
         return plan

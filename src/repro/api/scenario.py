@@ -123,11 +123,6 @@ class Scenario:
     backend: str = "auto"
     t_max: float = 10.0
     strict: bool = True   # topology solves: reject overcommitted domains
-    # Dispatch knobs, resolved by the backend substrate
-    # (repro.core.backend): None defers to REPRO_JAX_CUTOFF /
-    # REPRO_CHUNK_B or the process defaults.
-    jax_cutoff: int | None = None
-    chunk: int | None = None
 
     # -- constructors -------------------------------------------------------
 
@@ -256,13 +251,9 @@ class Scenario:
 
     def options(self, **kwargs) -> "Scenario":
         """Override solver options: ``utilization``, ``p0_factor``,
-        ``saturated``, ``backend``, ``t_max``, ``strict``, plus the
-        dispatch knobs ``jax_cutoff`` (the ``backend="auto"`` jax
-        threshold for this scenario; default ``REPRO_JAX_CUTOFF`` / 64)
-        and ``chunk`` (stream batched solves in slabs of this many
-        scenarios; default ``REPRO_CHUNK_B`` / off)."""
+        ``saturated``, ``backend``, ``t_max``, ``strict``."""
         allowed = {"utilization", "p0_factor", "saturated", "backend",
-                   "t_max", "strict", "jax_cutoff", "chunk"}
+                   "t_max", "strict"}
         bad = set(kwargs) - allowed
         if bad:
             raise TypeError(
@@ -292,11 +283,6 @@ class Scenario:
     def solver_options(self) -> dict:
         return dict(utilization=self.utilization,
                     p0_factor=self.p0_factor, saturated=self.saturated)
-
-    def dispatch_options(self) -> dict:
-        """The substrate-facing knobs (uniform across a batch)."""
-        return dict(backend=self.backend, jax_cutoff=self.jax_cutoff,
-                    chunk=self.chunk)
 
     # -- batching -----------------------------------------------------------
 
@@ -347,7 +333,7 @@ class ScenarioBatch:
         first = scenarios[0]
         for i, sc in enumerate(scenarios):
             if sc.solver_options() != first.solver_options() or \
-                    sc.dispatch_options() != first.dispatch_options():
+                    sc.backend != first.backend:
                 raise ValueError(
                     f"scenario {i} has different solver options than "
                     f"scenario 0; a batch is solved with one option set")

@@ -451,7 +451,7 @@ if HAVE_JAX:
 def fit_scaling(traces: TraceSet | Sequence[ScalingTrace], *,
                 utilization: str = "queue",
                 f_grid: np.ndarray | None = None, p0_factor: float = 0.5,
-                backend: str = "auto", jax_cutoff: int | None = None,
+                backend: str = "auto",
                 refine: str = "gauss-newton") -> ScalingFit:
     """Fit ``(f, b_s)`` for every scaling trace in one batched pass.
 
@@ -461,8 +461,7 @@ def fit_scaling(traces: TraceSet | Sequence[ScalingTrace], *,
     real-hardware measurements with a soft knee.
     ``backend``: ``"numpy"``, ``"jax"`` (vmapped + jitted), or ``"auto"``
     — resolved by the substrate (:func:`repro.core.backend.resolve`)
-    against the number of cells, honoring ``REPRO_JAX_CUTOFF`` / the
-    ``jax_cutoff`` override like every batched path.  The jitted fit
+    against the number of cells, like every batched path.  The jitted fit
     kernel — grid profile plus the sub-grid refinement — is one
     compiled plan per (cell-bucket, law, refiner) in the substrate's
     cache, so repeated fits of same-shaped trace sets skip
@@ -504,8 +503,7 @@ def fit_scaling(traces: TraceSet | Sequence[ScalingTrace], *,
         raise ValueError(f"unknown utilization mode {utilization!r}")
     f_grid = DEFAULT_F_GRID if f_grid is None else np.asarray(f_grid)
     n, y, mask, tr = traces.to_arrays()
-    backend = backend_mod.resolve(backend, n.shape[0],
-                                  jax_cutoff=jax_cutoff)
+    backend = backend_mod.resolve(backend, n.shape[0])
     with trace_mod.span("calibrate.fit", cells=int(n.shape[0]),
                         backend=backend, utilization=utilization,
                         refine=refine) as sp:

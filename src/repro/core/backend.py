@@ -12,10 +12,8 @@ This module is the single implementation:
   here); the other modules import it.
 * **resolution policy** — :func:`resolve` maps a requested backend
   (``"numpy"`` / ``"jax"`` / ``"auto"``) plus a batch size to the
-  backend that will actually run.  The ``auto`` cutoff is a
-  configurable knob: the ``REPRO_JAX_CUTOFF`` environment variable sets
-  the process default, and every batched entry point accepts a
-  per-call ``jax_cutoff=`` override.
+  backend that will actually run.  ``auto`` picks jax for batches of
+  at least :data:`DEFAULT_JAX_CUTOFF` scenarios.
 * **jitted-solver cache** — :func:`jitted` is a process-wide registry
   of compiled solver callables keyed by *padded shape bucket*
   (:func:`bucket` rounds sizes up to powers of two), so sweeping over
@@ -23,10 +21,6 @@ This module is the single implementation:
   per shape.  :func:`cache_stats` exposes hit/miss counters — the
   plan-overhead benchmark records the hit rate — and the XLA compiles
   the process made, counted from ``jax.monitoring`` events.
-* **chunked streaming** — :func:`run_chunked` executes an array
-  function over slabs of the batch axis and stitches the results, so a
-  B far beyond memory streams through a bounded working set
-  (``REPRO_CHUNK_B`` sets a process-wide default slab).
 * **numerics and compile cache** — :func:`x64` is the one float64 scope
   every jitted solver runs in, and :func:`enable_compile_cache` the one
   persistent-compilation-cache set-up every entry point calls.
@@ -41,7 +35,7 @@ from __future__ import annotations
 import os
 import pathlib
 import threading
-from typing import Callable, Sequence
+from typing import Callable
 
 import numpy as np
 
@@ -60,63 +54,11 @@ BACKENDS = ("numpy", "jax")
 
 #: Batches at least this large dispatch to the jitted jax solver under
 #: ``backend="auto"``: below it, jit dispatch overhead outweighs the
-#: vmap win (see BENCH_api.json).  Process default; override with the
-#: ``REPRO_JAX_CUTOFF`` environment variable or per call via
-#: ``jax_cutoff=``.
+#: vmap win (see BENCH_api.json).
 DEFAULT_JAX_CUTOFF = 64
-
-#: Environment variable overriding :data:`DEFAULT_JAX_CUTOFF`.
-JAX_CUTOFF_ENV = "REPRO_JAX_CUTOFF"
-
-#: Environment variable setting a process-wide default chunk size for
-#: :func:`run_chunked` consumers (0 / unset = no chunking).
-CHUNK_ENV = "REPRO_CHUNK_B"
-
-
-def _int_env(name: str, default: int) -> int:
-    raw = os.environ.get(name)
-    if raw is None or raw.strip() == "":
-        return default
-    try:
-        value = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"{name} must be an integer, got {raw!r}") from None
-    if value < 0:
-        raise ValueError(f"{name} must be >= 0, got {value}")
-    return value
-
-
-def jax_cutoff(override: int | None = None) -> int:
-    """Effective ``auto``-mode jax cutoff: the per-call ``override`` when
-    given, else ``REPRO_JAX_CUTOFF`` from the environment, else
-    :data:`DEFAULT_JAX_CUTOFF`.  The environment is re-read on every
-    call, so tests (and long-running servers) can retune the knob
-    without re-importing the library."""
-    if override is not None:
-        if override < 0:
-            raise ValueError(f"jax_cutoff must be >= 0, got {override}")
-        return int(override)
-    return _int_env(JAX_CUTOFF_ENV, DEFAULT_JAX_CUTOFF)
-
-
-def default_chunk(override: int | None = None) -> int | None:
-    """Effective streaming chunk size (``None`` = unchunked): the
-    per-call ``override`` when given, else ``REPRO_CHUNK_B`` from the
-    environment (0 / unset = off)."""
-    if override is not None:
-        if override < 1:
-            raise ValueError(f"chunk must be >= 1, got {override}")
-        return int(override)
-    value = _int_env(CHUNK_ENV, 0)
-    return value if value > 0 else None
-
-
-_effective_jax_cutoff = jax_cutoff  # alias: `resolve` shadows the name
 
 
 def resolve(backend: str, batch_size: int | None = None, *,
-            jax_cutoff: int | None = None,
             prefer: str = "jax") -> str:
     """Map a requested backend to the one that will run.
 
@@ -126,7 +68,7 @@ def resolve(backend: str, batch_size: int | None = None, *,
     cannot do).  ``"auto"`` resolves by policy:
 
     * ``prefer="jax"`` (the batched solvers): jax when importable and
-      the batch is at least :func:`jax_cutoff` scenarios (an unknown
+      the batch is at least :data:`DEFAULT_JAX_CUTOFF` scenarios (an unknown
       ``batch_size=None`` counts as large);
     * ``prefer="numpy"`` (the desync event engine, whose numpy path is
       the reference implementation): numpy, always — jax runs only on
@@ -141,8 +83,7 @@ def resolve(backend: str, batch_size: int | None = None, *,
             raise ValueError(f"unknown auto preference {prefer!r}")
         if not HAVE_JAX:
             return "numpy"
-        cutoff = _effective_jax_cutoff(jax_cutoff)
-        if batch_size is not None and batch_size < cutoff:
+        if batch_size is not None and batch_size < DEFAULT_JAX_CUTOFF:
             return "numpy"
         return "jax"
     if backend == "jax":
@@ -384,29 +325,3 @@ def pad_rows(arr: np.ndarray, rows: int) -> np.ndarray:
             f"cannot pad {arr.shape[0]} rows down to {rows}")
     pad = np.zeros((rows - arr.shape[0],) + arr.shape[1:], dtype=arr.dtype)
     return np.concatenate([arr, pad], axis=0)
-
-
-# ---------------------------------------------------------------------------
-# Chunked streaming execution
-# ---------------------------------------------------------------------------
-
-
-def run_chunked(fn: Callable[..., tuple], arrays: Sequence[np.ndarray],
-                chunk: int) -> tuple:
-    """Run ``fn(*slabs)`` over slabs of the shared batch axis and
-    concatenate the per-slab result tuples.
-
-    ``fn`` must map arrays of shape ``(b, ...)`` to a tuple of arrays
-    whose axis 0 is also ``b`` (the batched solvers' contract).  The
-    working set is one slab, so B far beyond memory streams through;
-    results are bit-for-bit the unchunked call because every solver on
-    the substrate is row-independent."""
-    if chunk < 1:
-        raise ValueError(f"chunk must be >= 1, got {chunk}")
-    B = arrays[0].shape[0]
-    if B <= chunk:
-        return fn(*arrays)
-    parts = [fn(*(a[i:i + chunk] for a in arrays))
-             for i in range(0, B, chunk)]
-    return tuple(np.concatenate([p[j] for p in parts], axis=0)
-                 for j in range(len(parts[0])))

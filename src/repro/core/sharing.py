@@ -496,17 +496,16 @@ if HAVE_JAX:
         in: one per :data:`_FETCH_PIECE_BYTES` begun."""
         return max(1, -(-8 * values // _FETCH_PIECE_BYTES))
 
-    def _build_jax_solver(mode: str, n_max: int,
-                          rows: tuple[int, int] | None = None):
+    def _build_jax_solver(mode: str, n_max: int, rows: tuple[int, int]):
         """Jitted vmap of the single-scenario solver for one shape
         bucket; registered in the substrate's process-wide cache.
 
-        The program takes ``n, f, bs`` as ``(Bb, G)`` arrays, or, given
-        ``rows`` = ``(Bb, G)``, flat as ``(Bb·G,)`` and laid out as rows
-        on the device: a TPU runtime copies a flat float64 array in as
-        it is, but splits a ``(rows, G)`` one into tiles by a host
-        transpose per 128 rows or so (110,595 of them for the three
-        inputs of a 2^19-row solve, each an event in a profiler trace).
+        The program takes ``n, f, bs`` flat as ``(Bb·G,)`` and lays them
+        out as ``rows`` = ``(Bb, G)`` on the device: a TPU runtime copies
+        a flat float64 array in as it is, but splits a ``(rows, G)`` one
+        into tiles by a host transpose per 128 rows or so (110,595 of
+        them for the three inputs of a 2^19-row solve, each an event in
+        a profiler trace).
         It returns its four outputs ``(b, alphas, util, bw)`` flat, each
         as a tuple of :func:`_fetch_pieces` consecutive pieces, which
         :func:`_fetch_outputs` copies back concurrently."""
@@ -515,8 +514,7 @@ if HAVE_JAX:
             in_axes=(0, 0, 0, None))
 
         def solve(n, f, bs, p0_aux):
-            if rows is not None:
-                n, f, bs = (x.reshape(rows) for x in (n, f, bs))
+            n, f, bs = (x.reshape(rows) for x in (n, f, bs))
             return tuple(
                 tuple(jnp.array_split(x, _fetch_pieces(x.size)))
                 for x in (a.ravel() for a in vmapped(n, f, bs, p0_aux)))
@@ -668,9 +666,7 @@ class BatchSharePrediction:
 def solve_arrays(n: np.ndarray, f: np.ndarray, bs: np.ndarray, *,
                  backend: str = "auto",
                  utilization: str | float = "recursion",
-                 p0_factor: float = 0.5, saturated: bool | None = None,
-                 jax_cutoff: int | None = None,
-                 chunk: int | None = None
+                 p0_factor: float = 0.5, saturated: bool | None = None
                  ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """The validated array core behind :func:`solve_batch`.
 
@@ -681,47 +677,24 @@ def solve_arrays(n: np.ndarray, f: np.ndarray, bs: np.ndarray, *,
 
     ``backend`` resolves through the substrate
     (:func:`repro.core.backend.resolve`): ``"auto"`` picks jax when
-    importable and ``B >= jax_cutoff`` (default
-    ``REPRO_JAX_CUTOFF`` / 64).  ``chunk`` streams the batch axis in
-    slabs of that many scenarios (default ``REPRO_CHUNK_B``; unset =
-    whole batch at once) — row-independent math, so chunking is
-    bit-for-bit the unchunked solve.
+    importable and ``B >= DEFAULT_JAX_CUTOFF`` (64).
     """
-    backend = backend_mod.resolve(backend, n.shape[0],
-                                  jax_cutoff=jax_cutoff)
+    backend = backend_mod.resolve(backend, n.shape[0])
     solve = _solve_arrays_jax if backend == "jax" else _solve_arrays_np
-    kwargs = dict(utilization=utilization, p0_factor=p0_factor,
-                  saturated=saturated)
-    eff_chunk = backend_mod.default_chunk(chunk)
-    chunked = eff_chunk is not None and n.shape[0] > eff_chunk
-
-    def dispatch():
-        if chunked:
-            return backend_mod.run_chunked(
-                lambda *arrs: solve(*arrs, **kwargs), (n, f, bs), eff_chunk)
-        return solve(n, f, bs, **kwargs)
-
     if not trace.enabled():  # hot path: no attr dicts, no span object
-        return dispatch()
+        return solve(n, f, bs, utilization=utilization,
+                     p0_factor=p0_factor, saturated=saturated)
     with trace.span("sharing.solve_arrays", backend=backend,
                     B=int(n.shape[0]), G=int(n.shape[1]),
-                    utilization=str(utilization),
-                    chunk=eff_chunk if chunked else None):
-        return dispatch()
-
-
-def resolve_backend(backend: str, batch_size: int | None = None, *,
-                    jax_cutoff: int | None = None) -> str:
-    """The backend a ``solve_batch``-family call with these parameters
-    will run on (compiled plans record this at trace time)."""
-    return backend_mod.resolve(backend, batch_size, jax_cutoff=jax_cutoff)
+                    utilization=str(utilization)):
+        return solve(n, f, bs, utilization=utilization,
+                     p0_factor=p0_factor, saturated=saturated)
 
 
 def solve_batch(n, f, bs, names=None, *,
                 utilization: str | float = "recursion",
                 p0_factor: float = 0.5, saturated: bool | None = None,
-                backend: str = "auto", jax_cutoff: int | None = None,
-                chunk: int | None = None) -> BatchSharePrediction:
+                backend: str = "auto") -> BatchSharePrediction:
     """Solve Eqs. 4–5 for a batch of scenarios.
 
     ``n``, ``f``, ``bs``: array-likes of shape ``(B, G)`` (a single ``(G,)``
@@ -729,11 +702,9 @@ def solve_batch(n, f, bs, names=None, *,
     ``names``: optional ``(B, G)`` nested sequence of group labels, carried
     through to :meth:`BatchSharePrediction.scenario` (padding entries "").
     ``backend``: ``"jax"`` (vmapped + jitted), ``"numpy"``, or ``"auto"``
-    (resolved by the substrate: jax when importable and ``B >=
-    jax_cutoff``, see :func:`repro.core.backend.resolve`).  Both backends
-    compute in float64 and agree with the scalar :func:`predict` to
-    ~1e-12 relative.  ``chunk`` streams huge batches in slabs (see
-    :func:`solve_arrays`).
+    (resolved by the substrate, see :func:`repro.core.backend.resolve`).
+    Both backends compute in float64 and agree with the scalar
+    :func:`predict` to ~1e-12 relative.
     """
     n = np.atleast_2d(np.asarray(n, dtype=np.float64))
     f = np.atleast_2d(np.asarray(f, dtype=np.float64))
@@ -750,8 +721,7 @@ def solve_batch(n, f, bs, names=None, *,
                 f"n{n.shape}")
     b, alphas, util, bw = solve_arrays(
         n, f, bs, backend=backend, utilization=utilization,
-        p0_factor=p0_factor, saturated=saturated, jax_cutoff=jax_cutoff,
-        chunk=chunk)
+        p0_factor=p0_factor, saturated=saturated)
     return BatchSharePrediction(n=n, f=f, bs=bs, b_overlap=b, alphas=alphas,
                                 util=util, bw_group=bw, names=names)
 
@@ -782,8 +752,7 @@ def solve_arrays_and_grad(n, f, bs, *, wrt=("f", "b_s"),
                           p0_factor: float = 0.5,
                           saturated: bool | None = None,
                           softmin_beta: float | None = None,
-                          backend: str = "auto",
-                          jax_cutoff: int | None = None
+                          backend: str = "auto"
                           ) -> tuple[tuple[np.ndarray, np.ndarray,
                                            np.ndarray, np.ndarray],
                                      dict[str, np.ndarray]]:
@@ -830,7 +799,7 @@ def solve_arrays_and_grad(n, f, bs, *, wrt=("f", "b_s"),
     mode, fixed_aux = _resolve_grad_mode(utilization, saturated)
     forward = solve_arrays(
         n, f, bs, backend=backend, utilization=utilization,
-        p0_factor=p0_factor, saturated=saturated, jax_cutoff=jax_cutoff)
+        p0_factor=p0_factor, saturated=saturated)
     B, G = n.shape
     aux = p0_factor if fixed_aux is None else fixed_aux
     n_max = int(n.sum(axis=-1).max()) if (n.size and mode == "recursion") \
@@ -906,8 +875,7 @@ def solve_placed_batch(n, f, bs, *, mask=None, names=None,
                        utilization: str | float = "recursion",
                        p0_factor: float = 0.5,
                        saturated: bool | None = None,
-                       backend: str = "auto", jax_cutoff: int | None = None,
-                       chunk: int | None = None
+                       backend: str = "auto"
                        ) -> PlacedBatchSharePrediction:
     """Solve Eqs. 4–5 for B placed scenarios in one flattened call.
 
@@ -925,8 +893,8 @@ def solve_placed_batch(n, f, bs, *, mask=None, names=None,
     are forced to the neutral ``n = f = bs = 0`` *before* the solve —
     whatever garbage the padding carries (even NaN) cannot perturb the
     occupied lanes, and empty padded domains attain exactly zero
-    bandwidth.  Dispatch (``backend``/``jax_cutoff``/``chunk``) resolves
-    on the flattened ``B·D`` row count through the substrate policy.
+    bandwidth.  ``backend`` resolves on the flattened ``B·D`` row count
+    through the substrate policy.
     """
     n = np.asarray(n, dtype=np.float64)
     if n.ndim == 2:
@@ -951,7 +919,7 @@ def solve_placed_batch(n, f, bs, *, mask=None, names=None,
         b, alphas, util, bw = solve_arrays(
             n.reshape(B * D, K), f.reshape(B * D, K), bs.reshape(B * D, K),
             backend=backend, utilization=utilization, p0_factor=p0_factor,
-            saturated=saturated, jax_cutoff=jax_cutoff, chunk=chunk)
+            saturated=saturated)
     return PlacedBatchSharePrediction(
         n=n, f=f, bs=bs, mask=mask,
         b_overlap=b.reshape(B, D), alphas=alphas.reshape(B, D, K),
@@ -965,8 +933,7 @@ def solve_placed_and_grad(n, f, bs, *, mask=None, names=None,
                           p0_factor: float = 0.5,
                           saturated: bool | None = None,
                           softmin_beta: float | None = None,
-                          backend: str = "auto",
-                          jax_cutoff: int | None = None
+                          backend: str = "auto"
                           ) -> tuple[PlacedBatchSharePrediction,
                                      dict[str, np.ndarray]]:
     """Placed-grid twin of :func:`solve_arrays_and_grad`.
@@ -1002,8 +969,7 @@ def solve_placed_and_grad(n, f, bs, *, mask=None, names=None,
     (b, alphas, util, bw), flat_grads = solve_arrays_and_grad(
         n.reshape(B * D, K), f.reshape(B * D, K), bs.reshape(B * D, K),
         wrt=wrt, utilization=utilization, p0_factor=p0_factor,
-        saturated=saturated, softmin_beta=softmin_beta, backend=backend,
-        jax_cutoff=jax_cutoff)
+        saturated=saturated, softmin_beta=softmin_beta, backend=backend)
     lane = mask[..., :, None] & mask[..., None, :]   # (B, D, K, K)
     grads = {name: np.where(lane, g.reshape(B, D, K, K), 0.0)
              for name, g in flat_grads.items()}

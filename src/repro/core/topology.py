@@ -462,8 +462,7 @@ def predict_placed_batch(topology: Topology,
     grid flattens to ``(B·D, K)`` rows, so backend dispatch and the
     process-wide jit cache see the same power-of-two buckets the
     unplaced batched path uses.  ``solver_kwargs`` (``utilization``,
-    ``saturated``, ``p0_factor``, ``backend``, ``jax_cutoff``,
-    ``chunk``) forward to the solver.
+    ``saturated``, ``p0_factor``, ``backend``) forward to the solver.
     """
     grid = pack_placed(topology, placements_batch, strict=strict)
     shares = solve_placed_batch(grid.n, grid.f, grid.bs, mask=grid.mask,

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro import api
-from repro.core import machine, sharing, table2, topology
+from repro.core import backend, machine, sharing, table2, topology
 from repro.core.sharing import HAVE_JAX
 
 
@@ -244,7 +244,7 @@ def test_small_batch_uses_numpy():
 @pytest.mark.skipif(not HAVE_JAX, reason="jax not importable")
 def test_large_batch_uses_jax():
     base = api.Scenario.on("CLX").run("DCOPY", 1).run("DDOT2", 1)
-    na = 1 + np.arange(api.JAX_BATCH_CUTOFF) % 19
+    na = 1 + np.arange(backend.DEFAULT_JAX_CUTOFF) % 19
     b = base.batch(np.stack([na, 20 - na], axis=-1))
     assert api.predict(b).engine == "jax"
     assert api.predict(b, backend="numpy").engine == "numpy"
@@ -378,15 +378,9 @@ def test_simulation_batch_fuses_inner_ensembles():
     assert res.members == ((0, 0), (0, 1), (1, 0), (1, 1), (1, 2))
     assert res.rows_for(0) == (0, 1)
     assert res.rows_for(1) == (2, 3, 4)
-    # Only forcing the legacy one-row-per-scenario path raises, with a
-    # suggestion pointing back at the fused default.
-    with pytest.raises(ValueError, match="fuse_ensembles"):
-        api.simulate(api.ScenarioBatch.of([sc_a, sc_b]),
-                     fuse_ensembles=False)
-    # ensemble=1 batches stay legal (and unmapped) on the legacy path.
+    # ensemble=1 batches map rows 1:1 to scenarios: no member map.
     one = api.simulate(api.ScenarioBatch.of(
-        [sc_a.with_noise(1e-5, seed=1), sc_b.with_noise(1e-5, seed=2)]),
-        fuse_ensembles=False)
+        [sc_a.with_noise(1e-5, seed=1), sc_b.with_noise(1e-5, seed=2)]))
     assert one.n_scenarios == 2
     assert one.members is None
 

@@ -305,8 +305,8 @@ def test_device_programs_keep_the_names_benchmark_readers_match(
 
     f64, i32 = jnp.float64, jnp.int32
     shape = jax.ShapeDtypeStruct
-    solver = sharing._build_jax_solver("recursion", 4)
-    name = _program_name(solver, *([shape((8, 3), f64)] * 3),
+    solver = sharing._build_jax_solver("recursion", 4, (8, 3))
+    name = _program_name(solver, *([shape((8 * 3,), f64)] * 3),
                          shape((), f64))
     assert re.search(_bench_pattern("solve_device_ms.sweep", monkeypatch),
                      name), name

@@ -285,7 +285,7 @@ def test_repeated_simulate_is_reproducible():
 
 
 # ---------------------------------------------------------------------------
-# Substrate: resolve policy, cutoff knob, jit cache, chunking
+# Substrate: resolve policy, jit cache
 # ---------------------------------------------------------------------------
 
 
@@ -303,53 +303,44 @@ def test_resolve_explicit_backends():
         assert backend.resolve("auto", None) == "numpy"
 
 
-def test_cutoff_env_and_override(monkeypatch):
-    monkeypatch.delenv(backend.JAX_CUTOFF_ENV, raising=False)
-    assert backend.jax_cutoff() == backend.DEFAULT_JAX_CUTOFF
-    monkeypatch.setenv(backend.JAX_CUTOFF_ENV, "4")
-    assert backend.jax_cutoff() == 4
-    assert backend.jax_cutoff(16) == 16           # per-call wins over env
-    if backend.HAVE_JAX:
-        assert backend.resolve("auto", 8) == "jax"
-        assert backend.resolve("auto", 8, jax_cutoff=16) == "numpy"
-    monkeypatch.setenv(backend.JAX_CUTOFF_ENV, "not-a-number")
-    with pytest.raises(ValueError, match="REPRO_JAX_CUTOFF"):
-        backend.jax_cutoff()
+def _nps4_placed_batch(b):
+    """B placed scenarios on the eight-domain ROME-2S-NPS4 node: the
+    flattened solve has B·8 rows."""
+    return api.ScenarioBatch.of([
+        api.Scenario.on("ROME").using("ROME-2S-NPS4")
+        .placed("DCOPY", 1 + i % 8, "ROME/s0/d0")
+        .placed("DDOT2", 2, "ROME/s1/d3")
+        for i in range(b)])
 
 
-@pytest.mark.skipif(not backend.HAVE_JAX, reason="jax not importable")
-def test_cutoff_honored_by_facade_and_solvers(monkeypatch):
-    batch = _sweep_batch(8)
-    assert api.predict(batch).engine == "numpy"            # below 64
-    assert api.predict(batch, jax_cutoff=4).engine == "jax"
-    monkeypatch.setenv(backend.JAX_CUTOFF_ENV, "4")
-    assert api.predict(batch).engine == "jax"
-    monkeypatch.delenv(backend.JAX_CUTOFF_ENV)
-    # Scenario-level knob flows through compile — and survives a run
-    # that re-resolves (backend="auto" must not discard it).
-    small = _sweep_batch(8, jax_cutoff=2)
-    plan = api.compile(small)
-    assert plan.engine == "jax"
-    assert plan.run(backend="auto").engine == "jax"
-    # Placed scenarios honor the knob too (their topology solve is a
-    # batched solve_batch call like any other).
-    placed = (api.Scenario.on("CLX").using("CLX-2S")
-              .placed("DCOPY", 4, "CLX/s0/d0"))
-    ref = api.predict(placed)
-    got = api.predict(placed, jax_cutoff=1)
-    assert got.bw_group == pytest.approx(ref.bw_group, rel=1e-9)
-    pplan = api.compile(placed.options(jax_cutoff=1, chunk=4))
-    assert pplan.solver_kwargs["jax_cutoff"] == 1
-    assert pplan.solver_kwargs["chunk"] == 4
-    # And the pre-facade batched paths resolve through the same policy.
-    assert sharing.resolve_backend("auto", 8) == "numpy"
-    assert sharing.resolve_backend("auto", 8, jax_cutoff=2) == "jax"
+def _fit_two_cells():
     from repro.calibrate import fit as fit_mod
     from repro.calibrate.traces import synthesize_scaling_trace
     traces = [synthesize_scaling_trace(k, "CLX", seed=0)
               for k in ("DCOPY", "DDOT2")]
-    assert fit_mod.fit_scaling(traces).backend == "numpy"   # 2 < 64
-    assert fit_mod.fit_scaling(traces, jax_cutoff=1).backend == "jax"
+    return fit_mod.fit_scaling(traces).backend
+
+
+_LARGE = "jax" if backend.HAVE_JAX else "numpy"
+
+
+@pytest.mark.parametrize("engine_of, expected", [
+    (lambda: api.compile(_sweep_batch(63)).engine, "numpy"),
+    (lambda: api.compile(_sweep_batch(64)).engine, _LARGE),
+    (lambda: api.compile(_nps4_placed_batch(7)).engine, "numpy"),  # 56 rows
+    (lambda: api.compile(_nps4_placed_batch(8)).engine, _LARGE),   # 64 rows
+    (_fit_two_cells, "numpy"),
+], ids=["batch-63", "batch-64", "nps4-placed-7", "nps4-placed-8",
+        "fit-2-cells"])
+def test_auto_resolves_at_the_fixed_cutoff(engine_of, expected):
+    assert backend.DEFAULT_JAX_CUTOFF == 64
+    assert engine_of() == expected
+
+
+@pytest.mark.parametrize("name", ["jax_cutoff", "chunk"])
+def test_removed_dispatch_options_are_refused(name):
+    with pytest.raises(TypeError, match="allowed: .*'backend'"):
+        api.Scenario.on("CLX").options(**{name: 1})
 
 
 @pytest.mark.skipif(not backend.HAVE_JAX, reason="jax not importable")
@@ -496,34 +487,6 @@ def test_fused_ensemble_seed_stability():
         for m, b in enumerate(rows):
             assert solo.records(m) == fused.records(b)
             assert solo.t_end[m] == fused.t_end[b]
-
-
-def test_chunked_solve_bit_for_bit(monkeypatch):
-    rng = np.random.default_rng(5)
-    n = rng.integers(0, 12, size=(23, 3)).astype(float)
-    f = rng.uniform(0.05, 1.0, size=(23, 3))
-    bs = rng.uniform(50, 200, size=(23, 3))
-    for bk in BACKENDS:
-        ref = sharing.solve_batch(n, f, bs, backend=bk)
-        got = sharing.solve_batch(n, f, bs, backend=bk, chunk=7)
-        np.testing.assert_array_equal(got.bw_group, ref.bw_group)
-        np.testing.assert_array_equal(got.b_overlap, ref.b_overlap)
-        np.testing.assert_array_equal(got.util, ref.util)
-    monkeypatch.setenv(backend.CHUNK_ENV, "5")
-    got = sharing.solve_batch(n, f, bs, backend="numpy")
-    ref2 = sharing.solve_batch(n, f, bs, backend="numpy", chunk=1000)
-    np.testing.assert_array_equal(got.bw_group, ref2.bw_group)
-
-
-def test_chunked_plan_run_bit_for_bit():
-    plan = api.compile(_sweep_batch(40))
-    ref = plan.run(backend="numpy")
-    got = plan.run(backend="numpy", chunk=16)
-    np.testing.assert_array_equal(got.bw_group, ref.bw_group)
-    # Scenario-level chunk option compiles into the plan.
-    chunky = api.compile(_sweep_batch(40, chunk=8))
-    np.testing.assert_array_equal(chunky.run(backend="numpy").bw_group,
-                                  ref.bw_group)
 
 
 def test_bucket_and_pad_rows():

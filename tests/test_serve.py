@@ -34,7 +34,7 @@ D0, D1 = "CLX/s0/d0", "CLX/s1/d0"
 
 def _scenarios(b, bk="numpy"):
     """b same-structure scenarios with distinct numeric payloads."""
-    return [api.Scenario.on("CLX", backend=bk, jax_cutoff=1)
+    return [api.Scenario.on("CLX", backend=bk)
             .run("DCOPY", 1 + i % 19).run("DDOT2", 20 - i % 19)
             for i in range(b)]
 
@@ -559,6 +559,27 @@ def test_placed_batch_posts_over_http():
             got, api.predict(sc, backend="numpy").bw_group, rtol=1e-12)
     # Every placed line of the node went through one plan per tick.
     assert stats["plan_cache"]["misses"] <= stats["coalescer"]["ticks"]
+
+
+def test_removed_dispatch_option_fails_its_line_alone():
+    from repro.serve import client
+
+    rows = _placed_rows(6, 2)
+    rows[2] = dict(rows[2], options={"jax_cutoff": 1})
+    with _Server() as srv:
+        out = client.solve("127.0.0.1", srv.port, rows, path="/v1/predict")
+    assert [r["id"] for r in out] == [r["id"] for r in rows]
+    bad = out[2]
+    assert not bad["ok"] and bad["status"] == 400
+    assert bad["error"].startswith("options: ") and \
+        "jax_cutoff" in bad["error"]
+    for row, r in zip(rows, out):
+        if row is rows[2]:
+            continue
+        assert r["ok"]
+        sc = parse_request(row).scenario
+        assert [g["bw"] for g in r["groups"]] == pytest.approx(
+            list(api.predict(sc, backend="numpy").bw_group), rel=1e-12)
 
 
 def test_parse_ahead_parses_a_post_in_pieces(monkeypatch):

@@ -4,9 +4,9 @@ in concurrent pieces.
 ``_solve_arrays_jax`` sends the jitted solver its inputs flat, has it
 return each of its four outputs flat and split into pieces of about 2
 MiB, copies every piece back at once and lands each output's pieces in
-one host buffer.  These tests hold that path to the solver's own four outputs fetched one by
-one, bit for bit, in every utilization mode, padded, chunked and in
-several pieces, and count the bytes it fetches.
+one host buffer.  These tests hold that path to the solver's own four
+outputs fetched one by one, bit for bit, in every utilization mode,
+padded and in several pieces, and count the bytes it fetches.
 """
 
 import functools
@@ -136,7 +136,7 @@ def test_inputs_cross_flat(monkeypatch):
     seen = []
     build = sharing._build_jax_solver
 
-    def recording(mode, n_max, rows=None):
+    def recording(mode, n_max, rows):
         solver = build(mode, n_max, rows)
 
         def solve(*args):
@@ -158,15 +158,6 @@ def test_empty_batches_and_groups_keep_their_shapes(B, G):
         n, f, bs, **MODES["recursion"])
     assert [x.shape for x in (b, alphas, util, bw)] == [
         (B,), (B, G), (B,), (B, G)]
-
-
-@pytest.mark.parametrize("chunk", [7, 64, 500])
-def test_chunked_flat_fetch_gives_the_same_bits(chunk):
-    n, f, bs = _inputs(1027, 3)
-    whole = sharing.solve_arrays(n, f, bs, backend="jax")
-    chunked = sharing.solve_arrays(n, f, bs, backend="jax", chunk=chunk)
-    for w, c in zip(whole, chunked):
-        np.testing.assert_array_equal(_bits(w), _bits(c))
 
 
 @pytest.mark.parametrize("B,G", [(64, 1), (100, 3), (1027, 8)])

@@ -94,17 +94,20 @@ def test_jacobi_v2_lowers_to_kernel(compile_hlo):
 
 @pytest.mark.parametrize("mode,n_max", [("recursion", 16), ("queue", 0)])
 def test_f64_sharing_solver_compiles(compile_hlo, mode, n_max):
-    solver = sharing._build_jax_solver(mode, n_max)
-    rows = ((SOLVER_B, 4), jnp.float64)
+    G = 4
+    solver = sharing._build_jax_solver(mode, n_max, (SOLVER_B, G))
+    flat = ((SOLVER_B * G,), jnp.float64)
     with backend.x64():
-        hlo = compile_hlo(solver, rows, rows, rows, ((), jnp.float64))
+        hlo = compile_hlo(solver, flat, flat, flat, ((), jnp.float64))
     assert "f64" in hlo
 
 
 def test_f64_sharing_solver_compiles_with_flat_inputs(compile_hlo):
-    G = 4
-    solver = sharing._build_jax_solver("recursion", 16, (SOLVER_B, G))
-    flat = ((SOLVER_B * G,), jnp.float64)
+    # Three lanes a row, as the placed sweeps lay out their groups: a
+    # minor dimension that does not fill the chip's tiles.
+    rows, G = SOLVER_B, 3
+    solver = sharing._build_jax_solver("recursion", 16, (rows, G))
+    flat = ((rows * G,), jnp.float64)
     with backend.x64():
         hlo = compile_hlo(solver, flat, flat, flat, ((), jnp.float64))
     assert "f64" in hlo
