@@ -462,21 +462,25 @@ if HAVE_JAX:
 
     def _solve_single_jax(n, f, bs, p0_aux, n_max, *, mode: str,
                           beta: float | None = None):
-        """One scenario (shape ``(G,)``); vmapped over the batch axis.
+        """One scenario ``(G,)``, or a batch of them as group-major planes
+        ``(G, ...)``: every sum runs over the leading group axis and the
+        per-scenario quantities broadcast against the planes, so the same
+        code solves one row (vmapped by the desync runner and the grad
+        solver) and a whole batch (the jitted batch solver).
 
         ``p0_aux`` carries ``p0_factor`` (recursion) or the fixed
         utilization (mode "fixed").  ``n_max`` is the loop bound, shared
-        across the batch so the vmapped ``fori_loop`` stays uniform.
+        across the batch so the ``fori_loop`` stays uniform.
         ``beta`` is the gradient path's softmin knob (see
         :func:`utilization_curve_jax`); every piece of this solver other
         than the saturation min is already smooth, so the whole Eq. 4–5
         chain is differentiable end to end.
         """
-        n_tot = n.sum()
+        n_tot = n.sum(axis=0)
         safe_n = jnp.maximum(n_tot, 1.0)
-        b = jnp.where(n_tot > 0, (n * bs).sum() / safe_n, 0.0)
+        b = jnp.where(n_tot > 0, (n * bs).sum(axis=0) / safe_n, 0.0)
         w = n * f
-        w_sum = w.sum()
+        w_sum = w.sum(axis=0)
         alphas = jnp.where(w_sum > 0, w / jnp.maximum(w_sum, _TINY), 0.0)
         f_mean = jnp.where(n_tot > 0, w_sum / safe_n, 0.0)
         active = n_tot > 0
@@ -497,27 +501,29 @@ if HAVE_JAX:
         return max(1, -(-8 * values // _FETCH_PIECE_BYTES))
 
     def _build_jax_solver(mode: str, n_max: int, rows: tuple[int, int]):
-        """Jitted vmap of the single-scenario solver for one shape
+        """The jitted batch solver for one ``rows`` = ``(Bb, G)`` shape
         bucket; registered in the substrate's process-wide cache.
 
-        The program takes ``n, f, bs`` flat as ``(Bb·G,)`` and lays them
-        out as ``rows`` = ``(Bb, G)`` on the device: a TPU runtime copies
-        a flat float64 array in as it is, but splits a ``(rows, G)`` one
-        into tiles by a host transpose per 128 rows or so (110,595 of
-        them for the three inputs of a 2^19-row solve, each an event in
-        a profiler trace).
-        It returns its four outputs ``(b, alphas, util, bw)`` flat, each
-        as a tuple of :func:`_fetch_pieces` consecutive pieces, which
-        :func:`_fetch_outputs` copies back concurrently."""
-        vmapped = jax.vmap(
-            functools.partial(_solve_single_jax, mode=mode, n_max=n_max),
-            in_axes=(0, 0, 0, None))
+        The program takes ``n, f, bs`` flat in group-major order, G
+        planes of ``Bb`` values one after another (:func:`_planes`), and
+        solves them as ``(G, Bb)`` planes: a plane of a power-of-two
+        bucket fills the chip's lanes, where a ``(Bb, G)`` row of three
+        groups fills 3 of 128.  A plane lies as full ``(·, 128)`` tiles
+        where the bucket allows.  It returns its four outputs ``(b,
+        alphas, util, bw)`` as planes (one for b and util, G for alphas
+        and bw), each plane as a tuple of :func:`_fetch_pieces`
+        consecutive pieces, which :func:`_fetch_outputs` copies back
+        concurrently."""
+        Bb, G = rows
+        planes = (G, Bb // 128, 128) if Bb % 128 == 0 else (G, Bb)
 
         def solve(n, f, bs, p0_aux):
-            n, f, bs = (x.reshape(rows) for x in (n, f, bs))
+            out = _solve_single_jax(*(x.reshape(planes) for x in (n, f, bs)),
+                                    p0_aux, n_max, mode=mode)
             return tuple(
-                tuple(jnp.array_split(x, _fetch_pieces(x.size)))
-                for x in (a.ravel() for a in vmapped(n, f, bs, p0_aux)))
+                tuple(tuple(jnp.array_split(plane, _fetch_pieces(Bb)))
+                      for plane in x.reshape(-1, Bb))
+                for x in out)
 
         # The program takes its name from this function: keep the
         # solver's, which device traces are searched for.
@@ -537,26 +543,30 @@ if HAVE_JAX:
         into, piece = job
         into[...] = np.asarray(piece)
 
-    def _fetch_outputs(outputs) -> list[np.ndarray]:
-        """Each flat output on the host, from its pieces on the device.
+    def _fetch_outputs(outputs, rows: int) -> list[np.ndarray]:
+        """Each output on the host, from its planes' pieces on the device.
 
-        Every piece's copy starts at once; the pieces of each output
-        land in their places in one buffer of its own, on a pool of
-        threads, as they arrive: the waits and the copies release the
-        interpreter lock, so the slow float64 copies overlap."""
-        for pieces in outputs:
-            for piece in pieces:
-                piece.copy_to_host_async()
-        flat, jobs = [], []
-        for pieces in outputs:
-            buf = np.empty(sum(p.shape[0] for p in pieces), np.float64)
-            flat.append(buf)
-            start = 0
-            for piece in pieces:
-                jobs.append((buf[start:start + piece.shape[0]], piece))
-                start += piece.shape[0]
+        Every piece's copy starts at once; an output of P planes of
+        ``rows`` values lands in a ``(rows, P)`` buffer of its own,
+        plane p in column p, so group-major planes land as rows with no
+        pass of their own.  The pieces land on a pool of threads as
+        they arrive: the waits and the copies release the interpreter
+        lock, so the slow float64 copies overlap."""
+        for planes in outputs:
+            for pieces in planes:
+                for piece in pieces:
+                    piece.copy_to_host_async()
+        landed, jobs = [], []
+        for planes in outputs:
+            buf = np.empty((rows, len(planes)), np.float64)
+            landed.append(buf)
+            for column, pieces in zip(buf.T, planes):
+                start = 0
+                for piece in pieces:
+                    jobs.append((column[start:start + piece.shape[0]], piece))
+                    start += piece.shape[0]
         list(_fetch_pool().map(_land, jobs))
-        return flat
+        return landed
 
     def _build_jax_grad_solver(mode: str, n_max: int, beta: float | None,
                                argnums: tuple[int, ...]):
@@ -571,15 +581,28 @@ if HAVE_JAX:
         jac = jax.jacrev(bw_of, argnums=argnums)
         return jax.jit(jax.vmap(jac, in_axes=(0, 0, 0, None)))
 
+    def _planes(a, rows: int) -> np.ndarray:
+        """``a`` of shape ``(B, G)`` as float64 group-major planes, flat:
+        column g padded with neutral zeros to ``rows``, the G columns one
+        after another.  One pass writes every value once."""
+        a = np.asarray(a)
+        B, G = a.shape
+        out = np.empty((G, rows), np.float64)
+        out[:, :B] = a.T
+        out[:, B:] = 0.0
+        return out.reshape(-1)
+
     def _solve_arrays_jax(n, f, bs, *, utilization, p0_factor, saturated):
         """JAX twin of :func:`_solve_arrays_np` (float64 via local x64).
 
         The jitted solver is fetched from the substrate's cache keyed by
-        the padded ``(B, G)`` bucket (plus the static recursion bound),
-        so nearby batch sizes share one XLA executable: inputs are
-        padded with neutral ``n = 0`` rows up to the bucket and the
-        outputs sliced back — exactly neutral in Eqs. 4–5, so the real
-        rows are bit-for-bit the unpadded solve.
+        the padded ``(Bb, G)`` bucket (plus the static recursion bound),
+        so nearby batch sizes share one XLA executable.  The inputs
+        cross as group-major planes padded with neutral ``n = 0`` rows
+        up to the bucket (:func:`_planes`, the ``sharing.jax.planes``
+        span), exactly neutral in Eqs. 4–5, so the real rows are
+        bit-for-bit the unpadded solve.  alphas and bw come back as
+        planes and land as ``(B, G)`` rows.
         """
         if saturated is True:
             mode, aux = "saturated", 0.0
@@ -589,36 +612,35 @@ if HAVE_JAX:
             mode, aux = utilization, p0_factor
         else:
             raise ValueError(f"unknown utilization mode {utilization!r}")
-        n = np.asarray(n, dtype=np.float64)
-        B, G = n.shape
-        # Only the recursion mode compiles an n-dependent loop; the
-        # other modes share one executable per (B, G) bucket.
-        n_max = int(n.sum(axis=-1).max()) if (n.size and mode == "recursion") \
-            else 0
-        n_max_b = backend_mod.bucket(n_max, minimum=MIN_RECURSION_BOUND) \
-            if n_max else 0
+        B, G = np.shape(n)
         Bb = backend_mod.bucket(B)
-        solver = backend_mod.jitted(
-            ("sharing.solve_batch", mode, Bb, G, n_max_b),
-            lambda: _build_jax_solver(mode, n_max_b, (Bb, G)))
         with backend_mod.x64():
             # The solver cannot start before its inputs arrive, so
             # waiting for them here costs nothing and keeps the copies
             # in under ``put`` rather than ``wait``.
             with trace.span("sharing.jax.put"):
-                args = jax.block_until_ready([jnp.asarray(
-                    backend_mod.pad_rows(np.asarray(a, dtype=np.float64),
-                                         Bb).reshape(-1), jnp.float64)
-                    for a in (n, f, bs)])
+                with trace.span("sharing.jax.planes"):
+                    planes = [_planes(a, Bb) for a in (n, f, bs)]
+                    # Only the recursion mode compiles an n-dependent
+                    # loop; the other modes share one executable per
+                    # (Bb, G) bucket.  A plane sum adds whole rows.
+                    n_max = int(planes[0].reshape(G, Bb).sum(axis=0).max()) \
+                        if (planes[0].size and mode == "recursion") else 0
+                args = jax.block_until_ready(
+                    [jnp.asarray(x, jnp.float64) for x in planes])
+            n_max_b = backend_mod.bucket(
+                n_max, minimum=MIN_RECURSION_BOUND) if n_max else 0
+            solver = backend_mod.jitted(
+                ("sharing.solve_batch", mode, Bb, G, n_max_b),
+                lambda: _build_jax_solver(mode, n_max_b, (Bb, G)))
             with trace.span("sharing.jax.wait"):
                 out = jax.block_until_ready(
                     solver(*args, jnp.float64(aux)))
             with trace.span("sharing.jax.get"):
-                b, alphas, util, bw = _fetch_outputs(out)
+                b, alphas, util, bw = _fetch_outputs(out, Bb)
                 metrics.counter("sharing.jax.get_bytes").inc(
                     b.nbytes + alphas.nbytes + util.nbytes + bw.nbytes)
-                return (b[:B], alphas.reshape(Bb, G)[:B], util[:B],
-                        bw.reshape(Bb, G)[:B])
+                return b[:B, 0], alphas[:B], util[:B, 0], bw[:B]
 
 
 @dataclasses.dataclass(frozen=True)

@@ -12,6 +12,7 @@ worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,14 +104,17 @@ def test_f64_sharing_solver_compiles(compile_hlo, mode, n_max):
 
 
 def test_f64_sharing_solver_compiles_with_flat_inputs(compile_hlo):
-    # Three lanes a row, as the placed sweeps lay out their groups: a
-    # minor dimension that does not fill the chip's tiles.
+    # Three groups a row, as the placed sweeps lay out their groups.  The
+    # program solves them as three planes of full (., 128) tiles: no
+    # array of the compiled program has a minor dimension of three.
     rows, G = SOLVER_B, 3
     solver = sharing._build_jax_solver("recursion", 16, (rows, G))
     flat = ((rows * G,), jnp.float64)
     with backend.x64():
         hlo = compile_hlo(solver, flat, flat, flat, ((), jnp.float64))
     assert "f64" in hlo
+    assert f"[3,{rows // 128},128]" in hlo
+    assert not re.search(r"\[\d+,3\]", hlo)
 
 
 def test_f64_desync_runner_compiles(compile_hlo):
